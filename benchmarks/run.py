@@ -88,6 +88,8 @@ def main(argv=None) -> None:
                     help="override timing iterations for every row "
                          "(util.ITERS_OVERRIDE)")
     args = ap.parse_args(argv)
+    from repro.launch import compile_cache
+    print(f"# compile cache: {compile_cache.configure()}")
     if args.iters:
         from benchmarks import util
         util.ITERS_OVERRIDE = args.iters

@@ -1,24 +1,16 @@
-"""jax-version compatibility shims (the container pins jax 0.4.37).
+"""Thin wrappers over the JAX 0.9 mesh and shard_map calls.
 
-The seed was written against newer jax (``jax.shard_map`` with
-``check_vma=``, ``jax.lax.axis_size``, ``jax.make_mesh(axis_types=...)``,
-``jax.set_mesh``).  These helpers bridge every band back to 0.4.x, where
-shard_map lives in ``jax.experimental.shard_map`` with ``check_rep=`` /
-``auto=``, axis sizes come from a constant-folded ``psum(1, axis)``, mesh
-axis types do not exist, and the Mesh object itself is the ambient-mesh
-context manager.
-
-Lives in ``repro.core`` (not ``repro.distributed``) so the core table
-modules can use the shims without a core -> distributed import cycle;
-``repro.distributed.sharding`` re-exports them for existing callers.
+Each wrapper is now the plain JAX 0.9.0 call (``requirements.txt`` pins
+that release); the names stay so that the many call sites need not change
+in this step.  Lives in ``repro.core`` (not ``repro.distributed``) so the
+core table modules can use them without a core -> distributed import
+cycle; ``repro.distributed.sharding`` re-exports them for existing callers.
 """
 
 from __future__ import annotations
 
-import contextlib
-import inspect
-
 import jax
+import numpy as np
 
 
 def supports_u64_sort() -> bool:
@@ -26,72 +18,39 @@ def supports_u64_sort() -> bool:
 
     jax's default (x64-disabled) config silently canonicalizes uint64
     arrays down to uint32, which would corrupt a packed two-plane sort
-    word — so the check is on the **effective** dtype, not the jax
-    version: ``canonicalize_dtype(uint64)`` only survives as uint64 when
-    ``jax_enable_x64`` is on (globally or via the
-    ``jax.experimental.enable_x64`` context).  Evaluated at trace time on
-    every call (it is one dict lookup) because the x64 config can toggle
-    mid-process; jit caches are keyed on that config, so a flip retraces
-    into the matching lane.
+    word — so the check is on the **effective** dtype:
+    ``canonicalize_dtype(uint64)`` only survives as uint64 when
+    ``jax_enable_x64`` is on (globally or inside ``jax.enable_x64(True)``).
+    Evaluated at trace time on every call (it is one dict lookup) because
+    the x64 config can toggle mid-process; jit caches are keyed on that
+    config, so a flip retraces into the matching lane.
     """
-    import numpy as np
-    try:
-        return jax.dtypes.canonicalize_dtype(np.uint64) == np.dtype("uint64")
-    except Exception:
-        return False
+    return jax.dtypes.canonicalize_dtype(np.uint64) == np.dtype("uint64")
 
 
 def axis_size_compat(axis) -> int:
-    """Static mesh-axis size inside shard_map, across jax versions
-    (``lax.axis_size`` is recent; ``psum(1, axis)`` constant-folds)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    return jax.lax.psum(1, axis)
+    """Static mesh-axis size inside shard_map."""
+    return jax.lax.axis_size(axis)
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs, axis_names=None):
-    """``jax.shard_map`` across jax versions.
-
-    Newest jax exposes ``jax.shard_map(..., check_vma=)``; the 0.6.x band
-    has ``jax.shard_map(..., check_rep=)``; older releases only have
-    ``jax.experimental.shard_map.shard_map(..., check_rep=)``.  Replication
-    checking is disabled either way (table pytrees carry per-shard state on
-    purpose).  ``axis_names`` restricts manual axes (new jax); on old jax
-    it maps to the complementary ``auto=`` set.
-    """
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    kwargs = {("check_vma" if "check_vma" in params else "check_rep"): False}
+    """``jax.shard_map`` with replication checking off (table pytrees carry
+    per-shard state on purpose); ``axis_names`` restricts the manual axes."""
+    kwargs = {}
     if axis_names is not None:
-        if "axis_names" in params:
-            kwargs["axis_names"] = frozenset(axis_names)
-        else:
-            kwargs["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
+        kwargs["axis_names"] = frozenset(axis_names)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False, **kwargs)
 
 
-def make_mesh_compat(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types when the version has them.
-
-    ``jax.sharding.AxisType`` / the ``axis_types=`` kwarg only exist on
-    newer jax; 0.4.x meshes behave like Auto everywhere already.
-    """
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            axis_shapes, axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
+def make_mesh_compat(axis_shapes, axis_names, devices=None):
+    """``jax.make_mesh`` with Auto axis types (over ``devices`` if given)."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        devices=devices)
 
 
 def set_mesh_compat(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` is recent; on 0.4.x the Mesh object itself is the
-    context manager.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager installing ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)

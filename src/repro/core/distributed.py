@@ -22,7 +22,7 @@ ops here are thin compositions of ``ownership_exchange`` /
 
 All functions here run *inside* shard_map (they use axis names); the
 ``shard_*`` wrappers at the bottom build it via
-``repro.core.compat.shard_map_compat``, which bridges jax versions.
+``repro.core.compat.shard_map_compat``.
 """
 
 from __future__ import annotations

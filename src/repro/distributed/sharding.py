@@ -185,7 +185,7 @@ def scalar_sharding(mesh: Mesh):
 
 
 # ---------------------------------------------------------------------------
-# jax-version compat + ownership partitioning (re-exported from repro.core)
+# mesh wrappers + ownership partitioning (re-exported from repro.core)
 # ---------------------------------------------------------------------------
 #
 # The hash-table side of the system (repro.core.distributed) assigns every
@@ -198,7 +198,7 @@ def scalar_sharding(mesh: Mesh):
 # co-partition uniformly and the sharded join accepts tuple-of-column keys
 # end-to-end.  The routing block itself (owner_of -> make_plan -> scatter ->
 # all_to_all) lives in ``repro.core.exchange`` — one implementation for the
-# distributed tables AND the relational shuffle — and the version shims in
+# distributed tables AND the relational shuffle — and the mesh wrappers in
 # ``repro.core.compat``; both are re-exported here for existing callers
 # (distributed code may import core, never the reverse).
 

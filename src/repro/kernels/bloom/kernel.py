@@ -5,7 +5,10 @@ exactly one block (one vector row — the "one memory transaction" property of
 blocked bloom filters, preserved on TPU as one VMEM row access).  Insert is
 a row read-OR-write; since the whole filter is VMEM-resident and grid steps
 are sequential, read-modify-write is race-free.  Query needs no
-serialization at all but uses the same row-gather structure.
+serialization at all but uses the same row-gather structure.  Keys and
+per-key results stream as (G, 1, T) arrays in SMEM blocks, as in the
+COPS tiles (``repro.kernels.cops.kernel``), whose block helpers this
+module shares.
 """
 
 from __future__ import annotations
@@ -17,6 +20,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core import hashing
+from repro.kernels.cops.kernel import (COMPILER_PARAMS, IN_PLACE,
+                                       load_resident, per_key_spec,
+                                       resident_spec)
 
 _U = jnp.uint32
 _I = jnp.int32
@@ -44,7 +50,7 @@ def _key_pattern(k, num_blocks, words, k_hashes, seed):
 
 def _insert_kernel(keys_ref, mask_ref, filt_in_ref, filt_ref,
                    *, num_blocks, words, k_hashes, seed):
-    del filt_in_ref
+    load_resident((filt_in_ref, filt_ref))
     tile = keys_ref.shape[1]
 
     def one_key(j, _):
@@ -62,22 +68,23 @@ def _insert_kernel(keys_ref, mask_ref, filt_in_ref, filt_ref,
     jax.lax.fori_loop(0, tile, one_key, 0)
 
 
-def insert_call(filt, keys2d, mask2d, *, k_hashes, seed, interpret=True):
+def insert_call(filt, keys3d, mask3d, *, k_hashes, seed, interpret=True):
+    """keys3d/mask3d: (G, 1, T). Returns the updated (blocks, words) filter."""
     num_blocks, words = filt.shape
-    g, tile = keys2d.shape
+    g, _, tile = keys3d.shape
     kern = functools.partial(_insert_kernel, num_blocks=num_blocks, words=words,
                              k_hashes=k_hashes, seed=seed)
-    full = pl.BlockSpec((num_blocks, words), lambda i: (0, 0))
-    row_tile = pl.BlockSpec((1, tile), lambda i: (i, 0))
+    row_tile = per_key_spec(tile)
     return pl.pallas_call(
         kern,
         grid=(g,),
-        in_specs=[row_tile, row_tile, full],
-        out_specs=full,
+        in_specs=[row_tile, row_tile, IN_PLACE],
+        out_specs=resident_spec((num_blocks, words)),
         out_shape=jax.ShapeDtypeStruct((num_blocks, words), _U),
         input_output_aliases={2: 0},
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(keys2d, mask2d, filt)
+    )(keys3d, mask3d, filt)
 
 
 def _query_kernel(keys_ref, filt_ref, out_ref,
@@ -95,18 +102,19 @@ def _query_kernel(keys_ref, filt_ref, out_ref,
     jax.lax.fori_loop(0, tile, one_key, 0)
 
 
-def query_call(filt, keys2d, *, k_hashes, seed, interpret=True):
+def query_call(filt, keys3d, *, k_hashes, seed, interpret=True):
+    """keys3d: (G, 1, T). Returns (G, 1, T) i32 hit flags."""
     num_blocks, words = filt.shape
-    g, tile = keys2d.shape
+    g, _, tile = keys3d.shape
     kern = functools.partial(_query_kernel, num_blocks=num_blocks, words=words,
                              k_hashes=k_hashes, seed=seed)
-    full = pl.BlockSpec((num_blocks, words), lambda i: (0, 0))
-    row_tile = pl.BlockSpec((1, tile), lambda i: (i, 0))
+    row_tile = per_key_spec(tile)
     return pl.pallas_call(
         kern,
         grid=(g,),
-        in_specs=[row_tile, full],
+        in_specs=[row_tile, resident_spec((num_blocks, words))],
         out_specs=row_tile,
-        out_shape=jax.ShapeDtypeStruct((g, tile), _I),
+        out_shape=jax.ShapeDtypeStruct((g, 1, tile), _I),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(keys2d, filt)
+    )(keys3d, filt)

@@ -10,17 +10,11 @@ import jax.numpy as jnp
 
 from repro.core import bloom as bloom_core
 from repro.kernels.bloom import kernel as K
-from repro.kernels.cops.ops import should_interpret
+from repro.kernels.cops.ops import _tile_batch as _tile
+from repro.kernels.cops.ops import check_resident, should_interpret
 
 _U = jnp.uint32
 _I = jnp.int32
-
-
-def _tile(x, tile, fill):
-    n = x.shape[0]
-    g = max(1, -(-n // tile))
-    x = jnp.pad(x, ((0, g * tile - n),), constant_values=fill)
-    return x.reshape(g, tile), n
 
 
 @functools.partial(jax.jit, static_argnames=("k_hashes", "seed", "tile", "interpret"))
@@ -48,6 +42,7 @@ def insert(f: bloom_core.BloomFilter, keys, mask=None) -> bloom_core.BloomFilter
     if mask is None:
         mask = jnp.ones(keys.shape, bool)
     words = bloom_core.pack_words(f)
+    check_resident("bloom insert", words.shape)
     words = insert_words(words, keys, mask, k_hashes=f.k, seed=f.seed,
                          interpret=should_interpret())
     return bloom_core.unpack_words(words, f.block_bits, f.k, f.seed)
@@ -55,5 +50,6 @@ def insert(f: bloom_core.BloomFilter, keys, mask=None) -> bloom_core.BloomFilter
 
 def contains(f: bloom_core.BloomFilter, keys) -> jax.Array:
     words = bloom_core.pack_words(f)
+    check_resident("bloom contains", words.shape)
     return query_words(words, jnp.asarray(keys), k_hashes=f.k, seed=f.seed,
                        interpret=should_interpret())

@@ -1,7 +1,11 @@
 """Jitted wrappers dispatching table ops to the COPS Pallas kernel.
 
 Handles batch padding/tiling, table-struct plumbing, and the
-interpret-mode switch (interpret=True everywhere except on real TPU).
+interpret-mode switch: kernels compile for the chip when JAX's default
+backend is a TPU and run in the Pallas interpreter everywhere else.
+Tables whose resident planes exceed ``kernel.VMEM_RESIDENT_LIMIT`` are
+refused with ``TableTooLargeForKernel`` — the tiles hold the whole table
+in VMEM.
 Kernel path restrictions (the ``*_ok`` eligibility checks below): SOA
 layout (``ops.planar``), 1-word values, and 1- or 2-plane keys — the
 2-plane composite/u64 key variants ride the ``*64`` tiles for insert,
@@ -14,7 +18,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -33,9 +36,21 @@ _KERNEL_SCHEMES = ("cops", "linear", "bucketed")
 
 
 def should_interpret() -> bool:
-    if os.environ.get("REPRO_PALLAS_INTERPRET"):
-        return os.environ["REPRO_PALLAS_INTERPRET"] != "0"
     return jax.default_backend() != "tpu"
+
+
+class TableTooLargeForKernel(ValueError):
+    """The table's planes do not fit the VMEM a kernel tile may hold."""
+
+
+def check_resident(what: str, *shapes) -> None:
+    """Raise unless the (rows, lanes) planes a tile keeps resident fit."""
+    need = K.resident_bytes(*shapes)
+    if need > K.VMEM_RESIDENT_LIMIT:
+        raise TableTooLargeForKernel(
+            f"{what}: the Pallas tile would hold {need} bytes of table planes "
+            f"{list(shapes)} in VMEM, above its limit of "
+            f"{K.VMEM_RESIDENT_LIMIT}; use backend='jax' for this table")
 
 
 def _probes(table) -> int:
@@ -54,11 +69,12 @@ def _kernel_ok(table) -> bool:
 
 
 def _tile_batch(x, tile, fill):
+    """(n,) -> padded (G, 1, tile) per-key array, and n."""
     n = x.shape[0]
     g = max(1, -(-n // tile))
     pad = g * tile - n
     x = jnp.pad(x, ((0, pad),), constant_values=fill)
-    return x.reshape(g, tile), n
+    return x.reshape(g, 1, tile), n
 
 
 @functools.partial(jax.jit, static_argnames=("seed", "max_probes", "scheme", "tile", "multi_value", "interpret"))
@@ -94,6 +110,8 @@ def _insert_dispatch(table, keys, values, mask, multi_value):
         mask = jnp.ones(values.shape, bool)
     interp = should_interpret()
     tile = min(K.DEFAULT_TILE, values.shape[0])
+    plane = (table.num_rows, table.window)
+    check_resident("insert", *[plane] * (table.key_words + 1))
     if table.key_words == 2:
         tk0, tk1 = table.store["keys"][0], table.store["keys"][1]
         tv = table.store["values"][0]
@@ -184,6 +202,7 @@ def update_groupby(table, agg, keys, payload, mask=None):
     if mask is None:
         mask = jnp.ones(keys.shape, bool)
     tile = min(K.DEFAULT_TILE, keys.shape[0])
+    check_resident("update_groupby", *[(table.num_rows, table.window)] * 3)
     tk = table.store["keys"][0]
     tv0, tv1 = table.store["values"][0], table.store["values"][1]
     tk, tv0, tv1, status = _update_jit(
@@ -273,6 +292,9 @@ def _fused_walk_pallas(table, keys_n, live, collect=True):
     """
     from repro.core import bulk_retrieve as br
     n = keys_n.shape[0]
+    plane = (table.num_rows, table.window)
+    check_resident("retrieve_all", *[plane] * (table.key_words
+                                               + (2 if collect else 0)))
     is_rep, rep_of = br.group_queries(keys_n, live)
     tile = min(K.DEFAULT_TILE, n)
     if table.key_words == 2:
@@ -327,23 +349,26 @@ def retrieve_all_multi(table, keys, out_capacity, mask=None):
 # bucket-list retrieval — kernel chain walk + the engine's compaction
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("tile", "sentinel", "chunk",
-                                             "interpret"))
+def _pool_rows(pool_cap):
+    return -(-pool_cap // K.POOL_LANES)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "sentinel", "interpret"))
 def _bucket_walk_jit(pool, ptr, cnt, bidx, act, sizes, cum, *, tile,
-                     sentinel, chunk, interpret):
-    p2, n = _tile_batch(ptr, tile, 0)
-    c2, _ = _tile_batch(cnt, tile, 0)
-    b2, _ = _tile_batch(bidx, tile, 0)
+                     sentinel, interpret):
+    p2, n = _tile_batch(ptr.astype(_I), tile, 0)
+    c2, _ = _tile_batch(cnt.astype(_I), tile, 0)
+    b2, _ = _tile_batch(bidx.astype(_I), tile, 0)
     a2, _ = _tile_batch(act.astype(_I), tile, 0)
     pool_cap = pool.shape[0]
-    # `chunk` slots of arena padding: a chunked window may run past the
-    # pool's edge on the last bucket (see the kernel header note)
-    qa0 = jnp.full((1, pool_cap + chunk), _I(sentinel), _I)
-    ra0 = jnp.zeros((1, pool_cap + chunk), _I)
-    qa, ra = K.bucket_walk_call(pool[None, :], qa0, ra0, p2, c2, b2, a2,
-                                sizes[None, :], cum[None, :], chunk=chunk,
+    shape = (_pool_rows(pool_cap), K.POOL_LANES)
+    pool2 = jnp.pad(pool, (0, shape[0] * shape[1] - pool_cap)).reshape(shape)
+    qa0 = jnp.full(shape, _I(sentinel), _I)
+    ra0 = jnp.zeros(shape, _I)
+    qa, ra = K.bucket_walk_call(pool2, qa0, ra0, p2, c2, b2, a2,
+                                sizes[None, :], cum[None, :],
                                 interpret=interpret)
-    return qa[0, :pool_cap], ra[0, :pool_cap]
+    return qa.reshape(-1)[:pool_cap], ra.reshape(-1)[:pool_cap]
 
 
 def bucket_retrieve_all(table, keys, out_capacity):
@@ -362,14 +387,15 @@ def bucket_retrieve_all(table, keys, out_capacity):
     n = keys_n.shape[0]
     if n == 0 or not (ks.ops.planar and ks.key_words == 1):
         return bl._retrieve_fused(table, keys_n, out_capacity)
+    check_resident("bucket_retrieve_all",
+                   *[(_pool_rows(table.pool_capacity), K.POOL_LANES)] * 3)
     is_rep, rep_of, found, ptr, rcnt, bidx, counts = bl._handle_probe(
         table, keys_n)
     tile = min(K.DEFAULT_TILE, n)
-    chunk = int(min(max(table.sizes), K.BUCKET_CHUNK))
     qa, ra = _bucket_walk_jit(
         table.pool, ptr, rcnt, bidx, found,
         jnp.asarray(table.sizes, _I), jnp.asarray(table.cum, _I),
-        tile=tile, sentinel=n, chunk=chunk, interpret=should_interpret())
+        tile=tile, sentinel=n, interpret=should_interpret())
     out, offsets, counts = br._emit(
         lambda s: table.pool[s][:, None], table.pool_capacity, out_capacity,
         counts, is_rep, rep_of, rcnt, qa, ra)
@@ -383,6 +409,8 @@ def retrieve(table, keys):
         return sv.retrieve(dataclasses.replace(table, backend="jax"), keys)
     keys = sv.normalize_key_batch(keys, table.key_words, "keys")
     tile = min(K.DEFAULT_TILE, keys.shape[0])
+    check_resident("retrieve", *[(table.num_rows, table.window)]
+                   * (table.key_words + 1))
     if table.key_words == 2:
         return _lookup64_jit(
             table.store["keys"][0], table.store["keys"][1],

@@ -32,7 +32,7 @@ def kmer_hashes(bases, *, k, tile=K.DEFAULT_TILE, interpret=True):
     bases = jnp.pad(bases, ((0, padded_len - bases.shape[0]),), constant_values=255)
     starts = jnp.arange(g) * tile
     idx = starts[:, None] + jnp.arange(tile + k - 1)[None, :]
-    tiles = bases[idx]
+    tiles = bases[idx][:, None, :]
     out = K.kmer_hash_call(tiles, k=k, interpret=interpret)
     return out.reshape(-1)[:n_out]
 

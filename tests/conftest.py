@@ -15,3 +15,9 @@ def unique_keys(rng, n, lo=1, hi=0xFFFFFF00):
     ks = rng.choice(np.arange(lo, lo + 4 * n, dtype=np.uint32), size=n,
                     replace=False)
     return ks
+
+
+def pytest_collection_modifyitems(items):
+    """Run the TPU compile rehearsals first: they take seconds, and a run
+    cut short by a time limit would otherwise never reach the file."""
+    items.sort(key=lambda item: item.path.name != "test_tpu_compile.py")

@@ -1,9 +1,8 @@
 """Multi-device tests (8 host devices, run in subprocesses so the main
 pytest process keeps its single real device — see conftest note).
 
-All mesh/shard_map construction goes through the jax-version shims in
-``repro.core.compat`` (re-exported by ``repro.distributed.sharding``) so
-the same tests pass on the container's jax 0.4.x and on current jax.
+All mesh/shard_map construction goes through the wrappers in
+``repro.core.compat`` (re-exported by ``repro.distributed.sharding``).
 """
 
 import json

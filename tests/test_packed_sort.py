@@ -7,15 +7,14 @@ compares exactly like the two-plane lexicographic pair, so EVERYTHING
 downstream of the general dedup lane — group structure, insert/update
 table state, statuses, fused retrieval layout, join pairs — must be
 bit-identical between the two lanes.  These tests run each op on the
-default config (two-plane lane) and again under
-``jax.experimental.enable_x64`` (packed lane) and diff the u32 outputs.
+default config (two-plane lane) and again under ``jax.enable_x64(True)``
+(packed lane) and diff the u32 outputs.
 """
 
 import numpy as np
 import pytest
 
 import jax
-import jax.experimental
 import jax.numpy as jnp
 
 from repro.core import bulk
@@ -28,7 +27,7 @@ _U = jnp.uint32
 
 
 def _x64():
-    return jax.experimental.enable_x64()
+    return jax.enable_x64(True)
 
 
 def _np(tree):
