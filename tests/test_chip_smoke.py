@@ -54,6 +54,40 @@ def test_keys_are_distinct_and_avoid_the_sentinels():
     assert keys.max() < 0xFFFFFFFE
 
 
+class _FakeChip:
+    platform, device_kind = "tpu", "TPU (stand-in)"
+
+    def memory_stats(self):
+        return {}
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_main_runs_the_fixed_sizes_and_reports_the_chips_used(
+        chips, monkeypatch, capsys):
+    """main() drives the certified sizes (2^26 pairs, 2^20 Pallas keys;
+    2^28 pairs in batches of 2^25 per chip on four) and its contract line
+    counts the chips the run used, not the chips the host shows."""
+    from repro.launch import compile_cache
+    ran = []
+    monkeypatch.setattr(jax, "devices", lambda: [_FakeChip()] * 4)
+    monkeypatch.setattr(compile_cache, "configure", lambda: "unset")
+    monkeypatch.setattr(cs, "make_mesh_compat", lambda *a, **k: "mesh")
+    monkeypatch.setattr(cs, "phase_insert_retrieve",
+                        lambda n, seed: ran.append(("insert_retrieve", n)))
+    monkeypatch.setattr(cs, "phase_pallas",
+                        lambda n, seed: ran.append(("pallas", n)))
+    monkeypatch.setattr(cs, "phase_distributed", lambda mesh, n, batches,
+                        seed: ran.append(("distributed", n, batches)))
+    assert cs.main(["--chips", str(chips)]) == 0
+    if chips == 1:
+        assert ran == [("insert_retrieve", 1 << 26), ("pallas", 1 << 20)]
+    else:
+        assert ran == [("distributed", 1 << 27, 2)]
+    last = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert last == {"ok": True, "device": {
+        "platform": "tpu", "kind": "TPU (stand-in)", "count": chips}}
+
+
 def _run(script, cwd):
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     return subprocess.run([sys.executable, str(script)], cwd=cwd, env=env,
