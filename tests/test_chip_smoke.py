@@ -91,7 +91,7 @@ def test_main_runs_the_fixed_sizes_and_reports_the_chips_used(
 def _run(script, cwd):
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     return subprocess.run([sys.executable, str(script)], cwd=cwd, env=env,
-                          capture_output=True, text=True, timeout=300)
+                          capture_output=True, text=True, timeout=100)
 
 
 @pytest.mark.parametrize("alone", [False, True], ids=["in_repo", "alone"])

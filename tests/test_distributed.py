@@ -1,5 +1,9 @@
-"""Multi-device tests (8 host devices, run in subprocesses so the main
+"""Multi-device tests (host devices, run in subprocesses so the main
 pytest process keeps its single real device — see conftest note).
+
+Each subprocess gets as many host devices as its mesh uses, and calls
+every ``shard_map`` program through ``jax.jit``: called eagerly, a
+``shard_map`` compiles each primitive of its body on its own.
 
 All mesh/shard_map construction goes through the wrappers in
 ``repro.core.compat`` (re-exported by ``repro.distributed.sharding``).
@@ -13,15 +17,14 @@ import textwrap
 
 import pytest
 
-_ENV = {**os.environ,
-        "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
-        "PYTHONPATH": "src"}
-
-
-def _run(code: str) -> str:
+def _run(code: str, devices: int = 8) -> str:
+    """Run ``code`` in a fresh interpreter over ``devices`` host devices,
+    well inside the test's own time limit (root conftest.py)."""
+    env = {**os.environ, "PYTHONPATH": "src", "XLA_FLAGS":
+           f"--xla_force_host_platform_device_count={devices}"}
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                       capture_output=True, text=True, timeout=540,
-                       env=_ENV, cwd=os.path.dirname(os.path.dirname(
+                       capture_output=True, text=True, timeout=100,
+                       env=env, cwd=os.path.dirname(os.path.dirname(
                            os.path.abspath(__file__))))
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr[-3000:]}"
     return r.stdout
@@ -35,17 +38,20 @@ class TestDistributedTables:
             from repro.core.compat import make_mesh_compat
             mesh = make_mesh_compat((8,), ('x',))
             table = dist.create_sharded(mesh, 'x', 2048, window=16)
+            shard_insert = jax.jit(dist.shard_insert, static_argnums=(0, 1))
+            shard_retrieve = jax.jit(dist.shard_retrieve,
+                                     static_argnums=(0, 1))
             n = 8 * 512
             keys = jnp.asarray(np.random.default_rng(0).permutation(
                 np.arange(1, n + 1, dtype=np.uint32)))
             vals = keys * 3
-            table, status, ov = dist.shard_insert(mesh, 'x', table, keys, vals)
+            table, status, ov = shard_insert(mesh, 'x', table, keys, vals)
             assert int(np.asarray(ov).sum()) == 0, 'exchange overflow'
             assert (np.asarray(status) != 2).all()
-            got, found, _ = dist.shard_retrieve(mesh, 'x', table, keys)
+            got, found, _ = shard_retrieve(mesh, 'x', table, keys)
             assert np.asarray(found).all()
             assert (np.asarray(got) == np.asarray(vals)).all()
-            miss, mf, _ = dist.shard_retrieve(
+            miss, mf, _ = shard_retrieve(
                 mesh, 'x', table,
                 jnp.arange(n + 10, n + 10 + n, dtype=jnp.uint32))
             assert not np.asarray(mf).any()
@@ -62,7 +68,8 @@ class TestDistributedTables:
             mesh = make_mesh_compat((8,), ('x',))
             table = dist.create_sharded(mesh, 'x', 1024, window=16)
             keys = jnp.arange(1, 2001, dtype=jnp.uint32)
-            table, _, ov = dist.shard_insert(mesh, 'x', table, keys, keys)
+            table, _, ov = jax.jit(dist.shard_insert, static_argnums=(0, 1))(
+                mesh, 'x', table, keys, keys)
             assert int(np.asarray(ov).sum()) == 0
             kp = np.asarray(table.key_planes())[:, 0]   # (8, p, W)
             seen = {}
@@ -98,13 +105,14 @@ class TestDistributedTables:
                 tl = dist._local(t)
                 tl, st = dist.insert_independent(tl, k, v)
                 return dist._relift(tl), st
-            f = shard_map_compat(ins, mesh, in_specs=(spec, P('x'), P('x')),
-                                 out_specs=(spec, P('x')))
+            f = jax.jit(shard_map_compat(ins, mesh,
+                                         in_specs=(spec, P('x'), P('x')),
+                                         out_specs=(spec, P('x'))))
             table, st = f(table, keys, vals)
             def ret(t, k):
                 return dist.retrieve_independent(dist._local(t), k, 'x')
-            g = shard_map_compat(ret, mesh, in_specs=(spec, P('x')),
-                                 out_specs=(P('x'), P('x')))
+            g = jax.jit(shard_map_compat(ret, mesh, in_specs=(spec, P('x')),
+                                         out_specs=(P('x'), P('x'))))
             got, found = g(table, keys)
             assert np.asarray(found).all()
             assert (np.asarray(got) == np.asarray(vals)).all()
@@ -122,21 +130,24 @@ class TestDistributedTables:
             table = dist.create_sharded(mesh, 'x', 1024, window=16)
             n = 8 * 128
             keys = jnp.arange(1, n + 1, dtype=jnp.uint32)
-            table, _, ov = dist.shard_insert(mesh, 'x', table, keys, keys)
+            table, _, ov = jax.jit(dist.shard_insert, static_argnums=(0, 1))(
+                mesh, 'x', table, keys, keys)
             assert int(np.asarray(ov).sum()) == 0
             spec = jax.tree.map(lambda _: P('x'), table)
             def er(t, k):
                 tl, erased, ov = dist.erase_distributed(dist._local(t), k, 'x')
                 return dist._relift(tl), erased, ov[None]
-            f = shard_map_compat(er, mesh, in_specs=(spec, P('x')),
-                                 out_specs=(spec, P('x'), P('x')))
+            f = jax.jit(shard_map_compat(er, mesh, in_specs=(spec, P('x')),
+                                         out_specs=(spec, P('x'), P('x'))))
             half = keys[:n // 2]
             pad = jnp.concatenate([half, jnp.arange(
                 2 * n, 2 * n + n // 2, dtype=jnp.uint32)])
             table, erased, ov = f(table, pad)
             assert int(np.asarray(ov).sum()) == 0
             assert np.asarray(erased)[:n // 2].all()
-            got, found, _ = dist.shard_retrieve(mesh, 'x', table, keys)
+            got, found, _ = jax.jit(dist.shard_retrieve,
+                                    static_argnums=(0, 1))(
+                mesh, 'x', table, keys)
             found = np.asarray(found)
             assert not found[:n // 2].any() and found[n // 2:].all()
             print('OK')
@@ -212,12 +223,12 @@ class TestPipelineParallel:
                 ref = block_fn({'w': blocks['w'][i]}, ref)
             staged = pp.stage_params(blocks, 4)
             spec = jax.tree.map(lambda _: P('pod'), staged)
-            f = shard_map_compat(
+            f = jax.jit(shard_map_compat(
                 lambda s, xx: pp.pipelined_apply(block_fn, s, xx, 'pod'),
-                mesh, in_specs=(spec, P()), out_specs=P())
+                mesh, in_specs=(spec, P()), out_specs=P()))
             out = f(staged, x)
             np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                        rtol=1e-4, atol=1e-4)
             print('OK')
-        """)
+        """, devices=4)
         assert "OK" in out

@@ -14,7 +14,8 @@ Three failure families, per ISSUE 10:
   replay the ownership exchange exactly: each shard ends with precisely
   its owned keys (``check_ownership``), nothing lost, lookup parity
   intact.  Host-simulated meshes here; the 8-device shard_map leg runs
-  in subprocesses via the harness from ``test_distributed.py``.
+  in subprocesses via the harness from ``test_distributed.py``, its
+  ``shard_map`` programs called through ``jax.jit``.
 """
 
 import os
@@ -41,11 +42,28 @@ _ENV = {**os.environ,
 
 def _run(code: str) -> str:
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                       capture_output=True, text=True, timeout=540,
+                       capture_output=True, text=True, timeout=100,
                        env=_ENV, cwd=os.path.dirname(os.path.dirname(
                            os.path.abspath(__file__))))
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr[-3000:]}"
     return r.stdout
+
+
+# Engines called through jax.jit: one compile per static configuration and
+# shape, reused by every test of a worker.  Called eagerly, they compile
+# their inner loops again on every call.
+sv_insert = jax.jit(sv.insert)
+sv_erase = jax.jit(sv.erase)
+sv_retrieve = jax.jit(sv.retrieve)
+mv_insert = jax.jit(mv.insert)
+mv_retrieve_all = jax.jit(mv.retrieve_all, static_argnames="out_capacity")
+bl_insert = jax.jit(bl.insert)
+bl_retrieve_all = jax.jit(bl.retrieve_all, static_argnames="out_capacity")
+counting_insert = jax.jit(counting.insert)
+counting_counts = jax.jit(counting.counts)
+elastic_insert = jax.jit(elastic.insert)
+elastic_erase = jax.jit(elastic.erase)
+elastic_lookup = jax.jit(elastic.lookup)
 
 
 def _keys(n, seed=0, lo=1, span=1 << 18):
@@ -69,16 +87,16 @@ def _sv_like(make):
     def build():
         t = make()
         ks, vs = _keys(150), _keys(150) * 7
-        t, _ = sv.insert(t, ks, vs)
+        t, _ = sv_insert(t, ks, vs)
         return t, (ks, vs)
 
     def mutate(t, live):
-        t, _ = sv.insert(t, _keys(60, seed=9, lo=1 << 20), _keys(60, seed=9))
-        t, _ = sv.erase(t, live[0][:40])
+        t, _ = sv_insert(t, _keys(60, seed=9, lo=1 << 20), _keys(60, seed=9))
+        t, _ = sv_erase(t, live[0][:40])
         return t
 
     def verify(t, live):
-        got, found = sv.retrieve(t, live[0])
+        got, found = sv_retrieve(t, live[0])
         assert bool(jnp.all(found))
         assert bool(jnp.all(got == live[1]))
     return build, mutate, verify
@@ -89,16 +107,16 @@ def _mv_case():
         t = mv.create(2048)
         ks = jnp.concatenate([_keys(100), _keys(100)])
         vs = jnp.concatenate([_keys(100) * 3, _keys(100) * 5])
-        t, _ = mv.insert(t, ks, vs)
+        t, _ = mv_insert(t, ks, vs)
         return t, (ks, vs)
 
     def mutate(t, live):
-        t, _ = mv.insert(t, _keys(50, seed=9, lo=1 << 20),
+        t, _ = mv_insert(t, _keys(50, seed=9, lo=1 << 20),
                          _keys(50, seed=9))
         return t
 
     def verify(t, live):
-        _, _, cnt = mv.retrieve_all(t, live[0][:100], 400)
+        _, _, cnt = mv_retrieve_all(t, live[0][:100], 400)
         assert bool(jnp.all(cnt == 2))
     return build, mutate, verify
 
@@ -110,7 +128,7 @@ def _mv_bucketed_case():
         t = mv.create(2048, kind="bucketed")
         ks = jnp.concatenate([_keys(100), _keys(100)])
         vs = jnp.concatenate([_keys(100) * 3, _keys(100) * 5])
-        t, _ = mv.insert(t, ks, vs)
+        t, _ = mv_insert(t, ks, vs)
         return t, (ks, vs)
     return build, m, v
 
@@ -119,15 +137,15 @@ def _counting_case():
     def build():
         t = counting.create(512)
         ks = _keys(80)
-        t, _ = counting.insert(t, jnp.concatenate([ks, ks, ks[:40]]))
+        t, _ = counting_insert(t, jnp.concatenate([ks, ks, ks[:40]]))
         return t, (ks,)
 
     def mutate(t, live):
-        t, _ = counting.insert(t, live[0])
+        t, _ = counting_insert(t, live[0])
         return t
 
     def verify(t, live):
-        c = counting.counts(t, live[0])
+        c = counting_counts(t, live[0])
         assert bool(jnp.all(c[:40] == 3)) and bool(jnp.all(c[40:] == 2))
     return build, mutate, verify
 
@@ -137,15 +155,15 @@ def _bucket_list_case():
         t = bl.create(256, 4096)
         ks = jnp.concatenate([_keys(80), _keys(80)])
         vs = jnp.arange(160, dtype=jnp.uint32)
-        t, _ = bl.insert(t, ks, vs)
+        t, _ = bl_insert(t, ks, vs)
         return t, (ks, vs)
 
     def mutate(t, live):
-        t, _ = bl.insert(t, _keys(40, seed=9, lo=1 << 20), _keys(40, seed=9))
+        t, _ = bl_insert(t, _keys(40, seed=9, lo=1 << 20), _keys(40, seed=9))
         return t
 
     def verify(t, live):
-        _, _, cnt = bl.retrieve_all(t, live[0][:80], 400)
+        _, _, cnt = bl_retrieve_all(t, live[0][:80], 400)
         assert bool(jnp.all(cnt == 2))
     return build, mutate, verify
 
@@ -176,15 +194,15 @@ class TestCrashRecovery:
             t = sv.create(1024, key_words=2, value_words=2)
             ks = jnp.stack([_keys(100), _keys(100, seed=3)], axis=1)
             vs = jnp.stack([_keys(100) * 3, _keys(100) * 5], axis=1)
-            t, _ = sv.insert(t, ks, vs)
+            t, _ = sv_insert(t, ks, vs)
             live = (ks, vs)
 
             def mutate(tt, lv):
-                tt, _ = sv.erase(tt, lv[0][:40])
+                tt, _ = sv_erase(tt, lv[0][:40])
                 return tt
 
             def verify(tt, lv):
-                got, found = sv.retrieve(tt, lv[0])
+                got, found = sv_retrieve(tt, lv[0])
                 assert bool(jnp.all(found))
                 assert bool(jnp.all(got == lv[1]))
         else:
@@ -291,7 +309,7 @@ class TestSnapshotWriter:
             jax.block_until_ready(t2.count)
             w.flush()
         restored = snapshot.load(p)
-        got, found = sv.retrieve(restored, live[0])
+        got, found = sv_retrieve(restored, live[0])
         assert bool(jnp.all(found)) and bool(jnp.all(got == live[1]))
         assert int(restored.count) == int(jnp.sum(
             jnp.ones_like(live[0], jnp.int32)))
@@ -301,7 +319,7 @@ class TestSnapshotWriter:
         t, _ = CASES["sv-soa"][0]()
         versions = [t]
         for i in range(4):
-            t, _ = sv.insert(t, _keys(20, seed=10 + i, lo=(1 << 20) * (i + 2)),
+            t, _ = sv_insert(t, _keys(20, seed=10 + i, lo=(1 << 20) * (i + 2)),
                              _keys(20, seed=10 + i))
             versions.append(t)
         p = str(tmp_path / "w.snap")
@@ -332,17 +350,17 @@ class TestShardedServing:
         for step in range(4):
             ins = _keys(200, seed=20 + step, span=1 << 12)
             vs = jnp.asarray(rng.integers(0, 2 ** 31, 200), jnp.uint32)
-            st, _ = elastic.insert(st, ins, vs)
+            st, _ = elastic_insert(st, ins, vs)
             for k, v in zip(np.asarray(ins).tolist(), np.asarray(vs).tolist()):
                 model[k] = v
             dels = _keys(60, seed=40 + step, span=1 << 12)
-            st, erased = elastic.erase(st, dels)
+            st, erased = elastic_erase(st, dels)
             for i, k in enumerate(np.asarray(dels).tolist()):
                 assert bool(erased[i]) == (k in model)
                 model.pop(k, None)
         universe = jnp.asarray(sorted(set(np.asarray(
             _keys(4096, seed=99, span=1 << 12)).tolist())), jnp.uint32)
-        got, found, stats = elastic.lookup(st, universe)
+        got, found, stats = elastic_lookup(st, universe)
         for i, k in enumerate(np.asarray(universe).tolist()):
             assert bool(found[i]) == (k in model), f"key {k}"
             if k in model:
@@ -351,9 +369,9 @@ class TestShardedServing:
 
     def test_absent_keys_skip_exchange(self):
         st = elastic.create(4, 2048)
-        st, _ = elastic.insert(st, _keys(500), _keys(500))
+        st, _ = elastic_insert(st, _keys(500), _keys(500))
         absent = _keys(1000, seed=7, lo=1 << 20)
-        _, found, stats = elastic.lookup(st, absent)
+        _, found, stats = elastic_lookup(st, absent)
         assert not bool(jnp.any(found))
         frac = int(stats["skips"]) / 1000
         assert frac >= 0.5, \
@@ -364,8 +382,8 @@ class TestShardedServing:
         exact for present keys — the one-sided bloom contract)."""
         st = elastic.create(4, 2048)
         ks = _keys(800)
-        st, _ = elastic.insert(st, ks, ks)
-        _, found, stats = elastic.lookup(st, ks)
+        st, _ = elastic_insert(st, ks, ks)
+        _, found, stats = elastic_lookup(st, ks)
         assert bool(jnp.all(found)), "filter produced a false negative"
         assert int(stats["skips"]) == 0
 
@@ -375,9 +393,9 @@ class TestShardedServing:
         advertising long-dead keys."""
         st = elastic.create(4, 2048)
         ks = _keys(600)
-        st, _ = elastic.insert(st, ks, ks)
+        st, _ = elastic_insert(st, ks, ks)
         dead, alive = ks[:500], ks[500:]
-        st, _ = elastic.erase(st, dead)
+        st, _ = elastic_erase(st, dead)
 
         def advertised(s, keys):
             words = sv.key_hash_word(
@@ -396,16 +414,16 @@ class TestShardedServing:
             "after compaction rebuild"
         # live keys must never be dropped by the rebuild
         assert bool(jnp.all(advertised(st, alive)))
-        got, found, _ = elastic.lookup(st, alive)
+        got, found, _ = elastic_lookup(st, alive)
         assert bool(jnp.all(found)) and bool(jnp.all(got == alive))
 
     def test_fill_fraction_only_grows_until_rebuild(self):
         st = elastic.create(2, 1024)
         fills = [float(bloom.fill_fraction(st.filters[0]))]
         for i in range(3):
-            st, _ = elastic.insert(st, _keys(100, seed=i, lo=1 + (i << 12)),
+            st, _ = elastic_insert(st, _keys(100, seed=i, lo=1 + (i << 12)),
                                    _keys(100, seed=i))
-            st, _ = elastic.erase(st, _keys(50, seed=i, lo=1 + (i << 12)))
+            st, _ = elastic_erase(st, _keys(50, seed=i, lo=1 + (i << 12)))
             fills.append(float(bloom.fill_fraction(st.filters[0])))
         assert all(b >= a for a, b in zip(fills, fills[1:])), fills
 
@@ -417,19 +435,19 @@ class TestElasticReshard:
     def test_reshard_ownership_exact(self, p_from, p_to):
         st = elastic.create(p_from, 4096)
         ks, vs = _keys(2000), _keys(2000) * 11
-        st, _ = elastic.insert(st, ks, vs)
+        st, _ = elastic_insert(st, ks, vs)
         st2 = elastic.reshard(st, p_to)
         assert st2.num_shards == p_to
         assert int(elastic.count(st2)) == 2000
         elastic.check_ownership(st2)
-        got, found, _ = elastic.lookup(st2, ks)
+        got, found, _ = elastic_lookup(st2, ks)
         assert bool(jnp.all(found)) and bool(jnp.all(got == vs))
 
     def test_restore_onto_resized_mesh(self, tmp_path):
         st = elastic.create(4, 4096)
         ks, vs = _keys(1500), _keys(1500) * 13
-        st, _ = elastic.insert(st, ks, vs)
-        st, _ = elastic.erase(st, ks[:500])
+        st, _ = elastic_insert(st, ks, vs)
+        st, _ = elastic_erase(st, ks[:500])
         d = str(tmp_path / "ckpt")
         elastic.save(st, d)
         # same count -> bit-exact shard restore
@@ -440,9 +458,9 @@ class TestElasticReshard:
         wide = elastic.load(d, num_shards=8)
         assert int(elastic.count(wide)) == 1000
         elastic.check_ownership(wide)
-        got, found, _ = elastic.lookup(wide, ks[500:])
+        got, found, _ = elastic_lookup(wide, ks[500:])
         assert bool(jnp.all(found)) and bool(jnp.all(got == vs[500:]))
-        gone, gfound, _ = elastic.lookup(wide, ks[:500])
+        gone, gfound, _ = elastic_lookup(wide, ks[:500])
         assert not bool(jnp.any(gfound))
 
     def test_kill_restore_resume(self, tmp_path):
@@ -470,7 +488,7 @@ class TestElasticReshard:
         del st                                     # the crash
         st2 = elastic.load(d)
         assert int(elastic.count(st2)) == pre_count
-        got, found, stats = elastic.lookup(st2, live_all)
+        got, found, stats = elastic_lookup(st2, live_all)
         assert int(stats["overflow"]) == 0
         assert bool(jnp.all(found))
         st2, _, steps, _ = elastic.serve_traffic(st2, traffic(3))
@@ -495,7 +513,9 @@ class TestElasticSubprocess:
             keys = jnp.asarray(np.random.default_rng(0).permutation(
                 np.arange(1, n + 1, dtype=np.uint32)))
             vals = keys * 3
-            table, status, ov = dist.shard_insert(mesh, 'x', table, keys, vals)
+            table, status, ov = jax.jit(dist.shard_insert,
+                                        static_argnums=(0, 1))(
+                mesh, 'x', table, keys, vals)
             assert int(np.asarray(ov).sum()) == 0
             d = {str(tmp_path)!r}
             os.makedirs(d, exist_ok=True)
@@ -534,7 +554,8 @@ class TestElasticSubprocess:
             keys = jnp.asarray(rng.choice(1 << 19, n, replace=False) + 1,
                                jnp.uint32)
             vals = keys * 5
-            table, _, ov = dist.shard_insert(mesh, 'x', table, keys, vals)
+            table, _, ov = jax.jit(dist.shard_insert, static_argnums=(0, 1))(
+                mesh, 'x', table, keys, vals)
             assert int(np.asarray(ov).sum()) == 0
             proto = bloom.create(16 * 2048)
             spec = jax.tree.map(lambda _: P('x'), table)
@@ -542,17 +563,17 @@ class TestElasticSubprocess:
                 t0 = dist._local(t)
                 return dist._relift(
                     bloom.rebuild_from_table(proto, t0).bits)
-            fbits = shard_map_compat(mk, mesh, in_specs=(spec,),
-                                     out_specs=P('x'))(table)
+            fbits = jax.jit(shard_map_compat(mk, mesh, in_specs=(spec,),
+                                             out_specs=P('x')))(table)
             import dataclasses
             def body(t, fb, k):
                 f = dataclasses.replace(proto, bits=fb[0])
                 v, fnd, sk, ov = dist.retrieve_distributed_filtered(
                     dist._local(t), f, k, 'x')
                 return v, fnd, sk[None], ov[None]
-            g = shard_map_compat(body, mesh,
-                                 in_specs=(spec, P('x'), P('x')),
-                                 out_specs=(P('x'), P('x'), P('x'), P('x')))
+            g = jax.jit(shard_map_compat(
+                body, mesh, in_specs=(spec, P('x'), P('x')),
+                out_specs=(P('x'), P('x'), P('x'), P('x'))))
             v, fnd, sk, ov = g(table, fbits, keys)
             assert bool(jnp.all(fnd)) and bool(jnp.all(v == vals))
             assert int(jnp.max(ov)) == 0

@@ -9,6 +9,7 @@ backends, the kv-cache free-list fix (no aliasing on exhaustion), and
 the pipeline dedup churn loop.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -341,6 +342,9 @@ class TestPipelineChurn:
         policy = migrate.GrowthPolicy(max_load_factor=0.7,
                                       max_tombstone_density=0.15)
         table = counting.create(64, window=8)
+        # the filter grows and compacts on the host; the forget pass is one
+        # program at one shape, compiled once for the loop
+        forget = jax.jit(pipeline.dedup_forget)
         c0 = REGISTRY.counter("table.compactions").value
         window = []                               # sliding retention window
         for step in range(16):
@@ -350,7 +354,7 @@ class TestPipelineChurn:
             assert bool(jnp.any(keep))            # fresh data passes
             window.append(batch["tokens"])
             if len(window) > 3:
-                table, _ = pipeline.dedup_forget(table, window.pop(0))
+                table, _ = forget(table, window.pop(0))
         # churn produced tombstones; the policy compacted at least once
         assert REGISTRY.counter("table.compactions").value > c0
         # the surviving window is still deduplicated exactly

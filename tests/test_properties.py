@@ -1,5 +1,14 @@
 """Hypothesis property tests: table semantics vs python reference models,
-and the structural invariants the probing scheme relies on."""
+and the structural invariants the probing scheme relies on.
+
+The engines are called through the jitted wrappers below, so that each
+static configuration (geometry, scheme, backend) compiles once for the
+whole sweep: eager calls recompile their inner loops on every call.
+Drawn lists are padded to their strategy's ``max_size`` so that every
+example has the same shapes: inserts and erases mask the padding off,
+lookups and ``mv.erase`` (no mask) pad with ``PAD_KEY``, a key outside
+every drawn universe, and the padded lanes are dropped before comparing.
+"""
 
 import dataclasses
 
@@ -14,7 +23,7 @@ from hypothesis import strategies as st
 
 from repro.core import bloom as bf
 from repro.core import bucket_list as bl
-from repro.core import hashing, layouts, probing
+from repro.core import hashing, layouts, migrate, probing
 from repro.core import multi_value as mv
 from repro.core import single_value as sv
 from repro.core.common import (
@@ -23,13 +32,54 @@ from repro.core.common import (
     STATUS_UPDATED,
     TOMBSTONE_KEY,
 )
+from repro.serving import elastic
 
 SETTINGS = settings(max_examples=20, deadline=None,
                     suppress_health_check=[HealthCheck.too_slow,
                                            HealthCheck.data_too_large])
 
-keys_st = st.lists(st.integers(1, 0xFFFF00), min_size=1, max_size=80)
+KEYS_MAX = 80
+keys_st = st.lists(st.integers(1, 0xFFFF00), min_size=1, max_size=KEYS_MAX)
 vals_st = st.integers(0, 0xFFFFFFFF)
+PAD_KEY = 0xFFFF01          # above every drawn key, and not a sentinel
+
+sv_insert = jax.jit(sv.insert)
+sv_erase = jax.jit(sv.erase)
+sv_retrieve = jax.jit(sv.retrieve)
+mv_insert = jax.jit(mv.insert)
+mv_erase = jax.jit(mv.erase)
+mv_count = jax.jit(mv.count_values)
+mv_retrieve_all = jax.jit(mv.retrieve_all, static_argnames="out_capacity")
+bl_insert = jax.jit(bl.insert)
+bl_count = jax.jit(bl.count_values)
+bl_retrieve_all = jax.jit(bl.retrieve_all, static_argnames="out_capacity")
+bf_insert = jax.jit(bf.insert)
+bf_contains = jax.jit(bf.contains)
+el_insert = jax.jit(elastic.insert)
+el_erase = jax.jit(elastic.erase)
+el_lookup = jax.jit(elastic.lookup)
+# Traced, compaction and migration skip their host-side live-count guard
+# and registry counters; the sweeps compare the live set themselves.
+el_compact_all = jax.jit(elastic.compact_all)
+mig_grow = jax.jit(migrate.grow, static_argnames="new_capacity")
+mig_compact = jax.jit(migrate.compact)
+
+
+@jax.jit
+def _advertised(filters, keys):
+    """Whether each key is set in its owner shard's bloom filter."""
+    words = sv.key_hash_word(sv.normalize_key_batch(keys, 1, "keys"))
+    owners = hashing.hash_owner(words, len(filters))
+    bits = jnp.stack([f.bits for f in filters])
+    return bf.contains_stack(filters[0], bits, owners, words)
+
+
+def _pad(xs, size, fill=PAD_KEY):
+    """``xs`` as a u32 array of ``size`` lanes (the tail ``fill``), and the
+    mask of its ``len(xs)`` live lanes."""
+    a = np.full(size, fill, np.uint32)
+    a[:len(xs)] = np.asarray(xs, np.uint32)
+    return jnp.asarray(a), jnp.asarray(np.arange(size) < len(xs))
 
 
 @st.composite
@@ -55,18 +105,18 @@ class TestSingleValueVsDict:
         for op, k, v in ops:
             ka = jnp.asarray([k], jnp.uint32)
             if op == "insert":
-                t, stt = sv.insert(t, ka, jnp.asarray([v], jnp.uint32))
+                t, stt = sv_insert(t, ka, jnp.asarray([v], jnp.uint32))
                 code = int(stt[0])
                 assert code == (STATUS_UPDATED if k in model
                                 else STATUS_INSERTED)
                 model[k] = v & 0xFFFFFFFF
             else:
-                t, er = sv.erase(t, ka)
+                t, er = sv_erase(t, ka)
                 assert bool(er[0]) == (k in model)
                 model.pop(k, None)
         assert int(t.count) == len(model)
         universe = jnp.arange(1, 41, dtype=jnp.uint32)
-        got, found = sv.retrieve(t, universe)
+        got, found = sv_retrieve(t, universe)
         for i, k in enumerate(range(1, 41)):
             assert bool(found[i]) == (k in model)
             if k in model:
@@ -79,7 +129,8 @@ class TestSingleValueVsDict:
         probe sequence (what makes stop-at-EMPTY retrieval sound)."""
         t = sv.create(256, window=8)
         u = np.unique(np.asarray(keys, np.uint32))
-        t, _ = sv.insert(t, jnp.asarray(u), jnp.asarray(u))
+        ku, live = _pad(u, KEYS_MAX)
+        t, _ = sv_insert(t, ku, ku, live)
         kp = np.asarray(t.key_planes()[0])          # (p, W)
         word = hashing.mix_murmur3(jnp.asarray(u))
         for k in u:
@@ -107,17 +158,17 @@ class TestMultiValueVsMultiDict:
     def test_multiset_semantics(self, pairs):
         t = mv.create(1024, window=16)
         model: dict = {}
-        ks = jnp.asarray([p[0] for p in pairs], jnp.uint32)
-        vs = jnp.asarray([p[1] for p in pairs], jnp.uint32)
+        ks, live = _pad([p[0] for p in pairs], 100)
+        vs, _ = _pad([p[1] for p in pairs], 100)
         for k, v in pairs:
             model.setdefault(k, []).append(v & 0xFFFFFFFF)
-        t, stt = mv.insert(t, ks, vs)
-        assert (np.asarray(stt) == STATUS_INSERTED).all()
+        t, stt = mv_insert(t, ks, vs, live)
+        assert (np.asarray(stt)[:len(pairs)] == STATUS_INSERTED).all()
         q = jnp.arange(1, 21, dtype=jnp.uint32)
-        cnt = mv.count_values(t, q)
+        cnt = mv_count(t, q)
         for i, k in enumerate(range(1, 21)):
             assert int(cnt[i]) == len(model.get(k, []))
-        out, off, _ = mv.retrieve_all(t, q, out_capacity=len(pairs))
+        out, off, _ = mv_retrieve_all(t, q, out_capacity=100)
         out, off = np.asarray(out), np.asarray(off)
         for i, k in enumerate(range(1, 21)):
             got = sorted(out[off[i]:off[i + 1]].tolist())
@@ -136,12 +187,12 @@ class TestBucketListVsMultiDict:
         model: dict = {}
         for k, v in pairs:
             model.setdefault(k, []).append(v & 0xFFFFFFFF)
-        ks = jnp.asarray([p[0] for p in pairs], jnp.uint32)
-        vs = jnp.asarray([p[1] for p in pairs], jnp.uint32)
-        t, stt = bl.insert(t, ks, vs)
-        assert (np.asarray(stt) == STATUS_INSERTED).all()
+        ks, live = _pad([p[0] for p in pairs], 80)
+        vs, _ = _pad([p[1] for p in pairs], 80)
+        t, stt = bl_insert(t, ks, vs, live)
+        assert (np.asarray(stt)[:len(pairs)] == STATUS_INSERTED).all()
         q = jnp.arange(1, 16, dtype=jnp.uint32)
-        out, off, cnt = bl.retrieve_all(t, q, out_capacity=len(pairs))
+        out, off, cnt = bl_retrieve_all(t, q, out_capacity=80)
         out, off = np.asarray(out), np.asarray(off)
         for i, k in enumerate(range(1, 16)):
             assert int(cnt[i]) == len(model.get(k, []))
@@ -171,17 +222,20 @@ class TestBucketListRoundTrip:
                   s0=s0, growth=growth)
         tb = bl.create(backend="jax", **kw)
         ts = bl.create(backend="scan", **kw)
-        ks = jnp.asarray([p[0] for p in pairs], jnp.uint32)
-        vs = jnp.asarray([p[1] for p in pairs], jnp.uint32)
-        rng = np.random.default_rng(len(pairs))
+        n = len(pairs)
+        ks, live = _pad([p[0] for p in pairs], 80)
+        vs, _ = _pad([p[1] for p in pairs], 80)
+        rng = np.random.default_rng(n)
         model: dict = {}
         for b in range(batches):
-            mask = (jnp.asarray(rng.random(len(pairs)) < 0.7)
-                    if use_mask else None)
-            tb, stb = bl.insert(tb, ks, vs + b, mask)
-            ts, sts = bl.insert(ts, ks, vs + b, mask)
+            mask = (live & jnp.asarray(np.pad(rng.random(n) < 0.7,
+                                              (0, 80 - n)))
+                    if use_mask else live)
+            tb, stb = bl_insert(tb, ks, vs + b, mask)
+            ts, sts = bl_insert(ts, ks, vs + b, mask)
             # backends bit-exact: statuses + handles + pool + allocator
-            np.testing.assert_array_equal(np.asarray(stb), np.asarray(sts))
+            np.testing.assert_array_equal(np.asarray(stb)[:n],
+                                          np.asarray(sts)[:n])
             for pb, ps in zip(jax.tree_util.tree_leaves(tb.key_store.store),
                               jax.tree_util.tree_leaves(ts.key_store.store)):
                 np.testing.assert_array_equal(np.asarray(pb), np.asarray(ps))
@@ -195,12 +249,12 @@ class TestBucketListRoundTrip:
                 if int(stb[i]) == STATUS_INSERTED:
                     model.setdefault(k, []).append((v + b) & 0xFFFFFFFF)
         q = jnp.arange(1, 16, dtype=jnp.uint32)
-        cb = bl.count_values(tb, q)
-        cs = bl.count_values(ts, q)
+        cb = bl_count(tb, q)
+        cs = bl_count(ts, q)
         np.testing.assert_array_equal(np.asarray(cb), np.asarray(cs))
         total = sum(map(len, model.values()))
-        outb, offb, cntb = bl.retrieve_all(tb, q, out_capacity=total + 1)
-        outs, offs, cnts = bl.retrieve_all(ts, q, out_capacity=total + 1)
+        outb, offb, cntb = bl_retrieve_all(tb, q, out_capacity=2 * 80 + 1)
+        outs, offs, cnts = bl_retrieve_all(ts, q, out_capacity=2 * 80 + 1)
         np.testing.assert_array_equal(np.asarray(outb), np.asarray(outs))
         np.testing.assert_array_equal(np.asarray(offb), np.asarray(offs))
         np.testing.assert_array_equal(np.asarray(cntb), np.asarray(cnts))
@@ -220,17 +274,17 @@ class TestBloomProperties:
     @given(keys=keys_st)
     def test_never_false_negative(self, keys):
         f = bf.create(1 << 10, k=3)
-        ka = jnp.asarray(np.asarray(keys, np.uint32))
-        f = bf.insert(f, ka)
-        assert bf.contains(f, ka).all()
+        ka, live = _pad(keys, KEYS_MAX)
+        f = bf_insert(f, ka, live)
+        assert bf_contains(f, ka)[:len(keys)].all()
 
     @SETTINGS
     @given(keys=keys_st)
     def test_insert_idempotent(self, keys):
         f = bf.create(1 << 10, k=3)
-        ka = jnp.asarray(np.asarray(keys, np.uint32))
-        f1 = bf.insert(f, ka)
-        f2 = bf.insert(f1, ka)
+        ka, live = _pad(keys, KEYS_MAX)
+        f1 = bf_insert(f, ka, live)
+        f2 = bf_insert(f1, ka, live)
         assert (f1.bits == f2.bits).all()
 
 
@@ -268,14 +322,14 @@ class TestInsertEraseRetrieveRoundTrip:
     def test_multi_value_round_trip(self, pairs, erase_keys, backend, window):
         t = mv.create(1024, window=window, backend=backend)
         model: dict = {}
-        ks = jnp.asarray([p[0] for p in pairs], jnp.uint32)
-        vs = jnp.asarray([p[1] for p in pairs], jnp.uint32)
+        ks, live = _pad([p[0] for p in pairs], 80)
+        vs, _ = _pad([p[1] for p in pairs], 80)
         for k, v in pairs:
             model.setdefault(k, []).append(v & 0xFFFFFFFF)
-        t, _ = mv.insert(t, ks, vs)
+        t, _ = mv_insert(t, ks, vs, live)
         if erase_keys:
-            ek = jnp.asarray(erase_keys, jnp.uint32)
-            t, ecnt = mv.erase(t, ek)
+            ek, _ = _pad(erase_keys, 15)
+            t, ecnt = mv_erase(t, ek)
             # every occurrence (duplicates included) reports the key's full
             # pre-erase multiplicity: the batch walk reads each window once
             for i, k in enumerate(erase_keys):
@@ -285,10 +339,10 @@ class TestInsertEraseRetrieveRoundTrip:
         # live pair count == surviving multiset size
         assert int(t.count) == sum(map(len, model.values()))
         q = jnp.arange(1, 31, dtype=jnp.uint32)
-        cnt = mv.count_values(t, q)
+        cnt = mv_count(t, q)
         for i, k in enumerate(range(1, 31)):
             assert int(cnt[i]) == len(model.get(k, []))
-        out, off, _ = mv.retrieve_all(t, q, out_capacity=len(pairs) + 1)
+        out, off, _ = mv_retrieve_all(t, q, out_capacity=80 + 1)
         out, off = np.asarray(out), np.asarray(off)
         for i, k in enumerate(range(1, 31)):
             got = sorted(out[off[i]:off[i + 1]].tolist())
@@ -304,15 +358,15 @@ class TestInsertEraseRetrieveRoundTrip:
         for op, k, v in ops:
             ka = jnp.asarray([k], jnp.uint32)
             if op == "insert":
-                t, _ = sv.insert(t, ka, jnp.asarray([v], jnp.uint32))
+                t, _ = sv_insert(t, ka, jnp.asarray([v], jnp.uint32))
                 model[k] = v & 0xFFFFFFFF
             else:
-                t, er = sv.erase(t, ka)
+                t, er = sv_erase(t, ka)
                 assert bool(er[0]) == (k in model)
                 model.pop(k, None)
         assert int(t.count) == len(model)   # live count == distinct live keys
         q = jnp.arange(1, 41, dtype=jnp.uint32)
-        got, found = sv.retrieve(t, q)
+        got, found = sv_retrieve(t, q)
         for i, k in enumerate(range(1, 41)):
             assert bool(found[i]) == (k in model)
             if k in model:
@@ -323,19 +377,21 @@ class TestInsertEraseRetrieveRoundTrip:
            backend=st.sampled_from(["jax", "scan"]))
     def test_erase_then_reinsert_recovers(self, keys, backend):
         """erase(k); insert(k, v') must behave as if k was never there."""
-        ka = jnp.asarray(np.unique(np.asarray(keys, np.uint32)))
+        u = np.unique(np.asarray(keys, np.uint32))
+        n = len(u)
+        ka, live = _pad(u, 60)
         t = sv.create(256, backend=backend)
-        t, _ = sv.insert(t, ka, ka)
-        t, er = sv.erase(t, ka)
-        assert np.asarray(er).all()
+        t, _ = sv_insert(t, ka, ka, live)
+        t, er = sv_erase(t, ka, live)
+        assert np.asarray(er)[:n].all()
         assert int(t.count) == 0
-        _, found = sv.retrieve(t, ka)
-        assert not np.asarray(found).any()  # erased keys retrieve empty
-        t, stt = sv.insert(t, ka, ka * 2)
-        assert (np.asarray(stt) == STATUS_INSERTED).all()
-        got, found = sv.retrieve(t, ka)
-        assert np.asarray(found).all()
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(ka) * 2)
+        _, found = sv_retrieve(t, ka)
+        assert not np.asarray(found)[:n].any()  # erased keys retrieve empty
+        t, stt = sv_insert(t, ka, ka * 2, live)
+        assert (np.asarray(stt)[:n] == STATUS_INSERTED).all()
+        got, found = sv_retrieve(t, ka)
+        assert np.asarray(found)[:n].all()
+        np.testing.assert_array_equal(np.asarray(got)[:n], u * 2)
 
 
 class TestBucketedRoundTrip:
@@ -355,18 +411,18 @@ class TestBucketedRoundTrip:
         for op, k, v in ops:
             ka = jnp.asarray([k], jnp.uint32)
             if op == "insert":
-                t, stt = sv.insert(t, ka, jnp.asarray([v], jnp.uint32))
+                t, stt = sv_insert(t, ka, jnp.asarray([v], jnp.uint32))
                 if int(stt[0]) <= STATUS_UPDATED:
                     assert int(stt[0]) == (STATUS_UPDATED if k in model
                                            else STATUS_INSERTED)
                     model[k] = v & 0xFFFFFFFF
             else:
-                t, er = sv.erase(t, ka)
+                t, er = sv_erase(t, ka)
                 assert bool(er[0]) == (k in model)
                 model.pop(k, None)
         assert int(t.count) == len(model)
         q = jnp.arange(1, 41, dtype=jnp.uint32)
-        got, found = sv.retrieve(t, q)
+        got, found = sv_retrieve(t, q)
         for i, k in enumerate(range(1, 41)):
             assert bool(found[i]) == (k in model)
             if k in model:
@@ -382,23 +438,23 @@ class TestBucketedRoundTrip:
                                              backend):
         t = mv.create(1024, window=16, kind="bucketed", backend=backend)
         model: dict = {}
-        ks = jnp.asarray([p[0] for p in pairs], jnp.uint32)
-        vs = jnp.asarray([p[1] for p in pairs], jnp.uint32)
-        t, stt = mv.insert(t, ks, vs)
+        ks, live = _pad([p[0] for p in pairs], 80)
+        vs, _ = _pad([p[1] for p in pairs], 80)
+        t, stt = mv_insert(t, ks, vs, live)
         for i, (k, v) in enumerate(pairs):
             if int(stt[i]) == STATUS_INSERTED:
                 model.setdefault(k, []).append(v & 0xFFFFFFFF)
         if erase_keys:
-            ek = jnp.asarray(erase_keys, jnp.uint32)
-            t, ecnt = mv.erase(t, ek)
+            ek, _ = _pad(erase_keys, 10)
+            t, ecnt = mv_erase(t, ek)
             for i, k in enumerate(erase_keys):
                 assert int(ecnt[i]) == len(model.get(k, []))
             for k in erase_keys:
                 model.pop(k, None)
         assert int(t.count) == sum(map(len, model.values()))
         q = jnp.arange(1, 26, dtype=jnp.uint32)
-        cnt = mv.count_values(t, q)
-        out, off, _ = mv.retrieve_all(t, q, out_capacity=len(pairs) + 1)
+        cnt = mv_count(t, q)
+        out, off, _ = mv_retrieve_all(t, q, out_capacity=80 + 1)
         out, off = np.asarray(out), np.asarray(off)
         for i, k in enumerate(range(1, 26)):
             assert int(cnt[i]) == len(model.get(k, []))
@@ -420,24 +476,28 @@ class TestCompositeKeyRoundTrip:
                           max_size=10),
            backend=st.sampled_from(["jax", "scan"]))
     def test_multi_value_composite_round_trip(self, pairs, erase, backend):
-        hi = jnp.asarray([p[0] for p in pairs], jnp.uint32)
-        lo = jnp.asarray([p[1] for p in pairs], jnp.uint32)
-        vs = jnp.asarray([p[2] for p in pairs], jnp.uint32)
+        # padding key (15, 15), packed 255: outside both drawn universes
+        n = len(pairs)
+        hi, live = _pad([p[0] for p in pairs], 60, 15)
+        lo, _ = _pad([p[1] for p in pairs], 60, 15)
+        vs, _ = _pad([p[2] for p in pairs], 60)
         packed = (hi << 4) | lo
         model: dict = {}
         for h, l, v in pairs:
             model.setdefault((h, l), []).append(v & 0xFFFFFFFF)
         tc = mv.create(512, key_words=2, backend=backend)
         tp = mv.create(512, key_words=1, backend=backend)
-        tc, st_c = mv.insert(tc, (hi, lo), vs)
-        tp, st_p = mv.insert(tp, packed, vs)
-        np.testing.assert_array_equal(np.asarray(st_c), np.asarray(st_p))
+        tc, st_c = mv_insert(tc, (hi, lo), vs, live)
+        tp, st_p = mv_insert(tp, packed, vs, live)
+        np.testing.assert_array_equal(np.asarray(st_c)[:n],
+                                      np.asarray(st_p)[:n])
         if erase:
-            eh = jnp.asarray([e[0] for e in erase], jnp.uint32)
-            el = jnp.asarray([e[1] for e in erase], jnp.uint32)
-            tc, ec = mv.erase(tc, (eh, el))
-            tp, ep = mv.erase(tp, (eh << 4) | el)
-            np.testing.assert_array_equal(np.asarray(ec), np.asarray(ep))
+            eh, _ = _pad([e[0] for e in erase], 10, 15)
+            el, _ = _pad([e[1] for e in erase], 10, 15)
+            tc, ec = mv_erase(tc, (eh, el))
+            tp, ep = mv_erase(tp, (eh << 4) | el)
+            np.testing.assert_array_equal(np.asarray(ec)[:len(erase)],
+                                          np.asarray(ep)[:len(erase)])
             for h, l in erase:
                 model.pop((h, l), None)
         assert int(tc.count) == sum(map(len, model.values()))
@@ -446,9 +506,9 @@ class TestCompositeKeyRoundTrip:
                          jnp.uint32)
         ql = jnp.asarray([l for _ in range(6) for l in range(1, 7)],
                          jnp.uint32)
-        cap = len(pairs) + 1
-        out_c, off_c, cnt_c = mv.retrieve_all(tc, (qh, ql), cap)
-        out_p, off_p, cnt_p = mv.retrieve_all(tp, (qh << 4) | ql, cap)
+        cap = 60 + 1
+        out_c, off_c, cnt_c = mv_retrieve_all(tc, (qh, ql), cap)
+        out_p, off_p, cnt_p = mv_retrieve_all(tp, (qh << 4) | ql, cap)
         np.testing.assert_array_equal(np.asarray(out_c), np.asarray(out_p))
         np.testing.assert_array_equal(np.asarray(off_c), np.asarray(off_p))
         np.testing.assert_array_equal(np.asarray(cnt_c), np.asarray(cnt_p))
@@ -471,12 +531,12 @@ class TestCompositeKeyRoundTrip:
         for op, h, l, v in ops:
             key = (jnp.asarray([h], jnp.uint32), jnp.asarray([l], jnp.uint32))
             if op == "insert":
-                t, stt = sv.insert(t, key, jnp.asarray([v], jnp.uint32))
+                t, stt = sv_insert(t, key, jnp.asarray([v], jnp.uint32))
                 assert int(stt[0]) == (STATUS_UPDATED if (h, l) in model
                                        else STATUS_INSERTED)
                 model[(h, l)] = v & 0xFFFFFFFF
             else:
-                t, er = sv.erase(t, key)
+                t, er = sv_erase(t, key)
                 assert bool(er[0]) == ((h, l) in model)
                 model.pop((h, l), None)
         assert int(t.count) == len(model)
@@ -484,7 +544,7 @@ class TestCompositeKeyRoundTrip:
                          jnp.uint32)
         ql = jnp.asarray([l for _ in range(5) for l in range(1, 6)],
                          jnp.uint32)
-        got, found = sv.retrieve(t, (qh, ql))
+        got, found = sv_retrieve(t, (qh, ql))
         for i, (h, l) in enumerate(zip(np.asarray(qh), np.asarray(ql))):
             assert bool(found[i]) == ((int(h), int(l)) in model)
             if (int(h), int(l)) in model:
@@ -501,24 +561,23 @@ class TestMigrationRoundTrip:
     @given(ops=ops_st(), window=st.sampled_from([4, 16]),
            new_capacity=st.sampled_from([600, 2048]))
     def test_single_value_migration(self, ops, window, new_capacity):
-        from repro.core import migrate
         from repro.obs import metrics
         t = sv.create(512, window=window)
         model = {}
         for op, k, v in ops:
             ka = jnp.asarray([k], jnp.uint32)
             if op == "insert":
-                t, _ = sv.insert(t, ka, jnp.asarray([v], jnp.uint32))
+                t, _ = sv_insert(t, ka, jnp.asarray([v], jnp.uint32))
                 model[k] = v & 0xFFFFFFFF
             else:
-                t, _ = sv.erase(t, ka)
+                t, _ = sv_erase(t, ka)
                 model.pop(k, None)
-        t = migrate.compact(migrate.grow(t, new_capacity))
+        t = mig_compact(mig_grow(t, new_capacity))
         _, tomb, _ = metrics.slot_stats(t.ops, t.store)
         assert int(tomb) == 0                  # migration drops tombstones
         assert int(t.count) == len(model)
         q = jnp.arange(1, 41, dtype=jnp.uint32)
-        got, found = sv.retrieve(t, q)
+        got, found = sv_retrieve(t, q)
         for i, k in enumerate(range(1, 41)):
             assert bool(found[i]) == (k in model)
             if k in model:
@@ -530,23 +589,22 @@ class TestMigrationRoundTrip:
                           min_size=1, max_size=80),
            erase_keys=st.lists(st.integers(1, 25), max_size=10))
     def test_multi_value_migration(self, pairs, erase_keys):
-        from repro.core import migrate
         t = mv.create(512, window=8)
         model: dict = {}
-        ks = jnp.asarray([p[0] for p in pairs], jnp.uint32)
-        vs = jnp.asarray([p[1] for p in pairs], jnp.uint32)
+        ks, live = _pad([p[0] for p in pairs], 80)
+        vs, _ = _pad([p[1] for p in pairs], 80)
         for k, v in pairs:
             model.setdefault(k, []).append(v & 0xFFFFFFFF)
-        t, _ = mv.insert(t, ks, vs)
+        t, _ = mv_insert(t, ks, vs, live)
         if erase_keys:
-            t, _ = mv.erase(t, jnp.asarray(erase_keys, jnp.uint32))
+            t, _ = mv_erase(t, _pad(erase_keys, 10)[0])
             for k in erase_keys:
                 model.pop(k, None)
-        t = migrate.compact(migrate.grow(t, 2048))
+        t = mig_compact(mig_grow(t, 2048))
         assert int(t.count) == sum(map(len, model.values()))
         q = jnp.arange(1, 21, dtype=jnp.uint32)
-        cnt = mv.count_values(t, q)
-        out, off, _ = mv.retrieve_all(t, q, out_capacity=len(pairs) + 1)
+        cnt = mv_count(t, q)
+        out, off, _ = mv_retrieve_all(t, q, out_capacity=80 + 1)
         out, off = np.asarray(out), np.asarray(off)
         for i, k in enumerate(range(1, 21)):
             assert int(cnt[i]) == len(model.get(k, []))  # fan-out preserved
@@ -560,16 +618,15 @@ class TestMigrationRoundTrip:
            s0=st.sampled_from([1, 2]),
            growth=st.sampled_from([1.0, 1.5]))
     def test_bucket_list_migration(self, pairs, s0, growth):
-        from repro.core import migrate
         t = bl.create(128, pool_capacity=1024, s0=s0, growth=growth)
-        ks = jnp.asarray([p[0] for p in pairs], jnp.uint32)
-        vs = jnp.asarray([p[1] for p in pairs], jnp.uint32)
-        t, stt = bl.insert(t, ks, vs)
-        assert (np.asarray(stt) == STATUS_INSERTED).all()
+        ks, live = _pad([p[0] for p in pairs], 60)
+        vs, _ = _pad([p[1] for p in pairs], 60)
+        t, stt = bl_insert(t, ks, vs, live)
+        assert (np.asarray(stt)[:len(pairs)] == STATUS_INSERTED).all()
         q = jnp.arange(1, 13, dtype=jnp.uint32)
-        want = bl.retrieve_all(t, q, out_capacity=len(pairs))
-        fresh = migrate.compact(migrate.grow(t, 512))
-        got = bl.retrieve_all(fresh, q, out_capacity=len(pairs))
+        want = bl_retrieve_all(t, q, out_capacity=60)
+        fresh = mig_compact(mig_grow(t, 512))
+        got = bl_retrieve_all(fresh, q, out_capacity=60)
         # migration preserves per-key insertion order bit-exactly
         for w, g in zip(want, got):
             np.testing.assert_array_equal(np.asarray(w), np.asarray(g))
@@ -580,13 +637,15 @@ class TestLayoutEquivalence:
     @given(keys=keys_st, window=st.sampled_from([8, 32]))
     def test_all_layouts_same_results(self, keys, window):
         u = np.unique(np.asarray(keys, np.uint32))
+        n = len(u)
         vals = (u * 31 + 7).astype(np.uint32)
+        ku, live = _pad(u, KEYS_MAX)
         results = {}
         for layout in layouts.LAYOUTS:
             t = sv.create(512, window=window, layout=layout)
-            t, _ = sv.insert(t, jnp.asarray(u), jnp.asarray(vals))
-            got, found = sv.retrieve(t, jnp.asarray(u))
-            results[layout] = (np.asarray(got), np.asarray(found))
+            t, _ = sv_insert(t, ku, _pad(vals, KEYS_MAX)[0], live)
+            got, found = sv_retrieve(t, ku)
+            results[layout] = (np.asarray(got)[:n], np.asarray(found)[:n])
         a = results["soa"]
         for layout in ("aos", "packed"):
             assert (results[layout][0] == a[0]).all()
@@ -607,22 +666,22 @@ class TestSnapshotProperties:
         for op, k, v in ops:
             ka = jnp.asarray([k], jnp.uint32)
             if op == "insert":
-                t, _ = sv.insert(t, ka, jnp.asarray([v], jnp.uint32))
+                t, _ = sv_insert(t, ka, jnp.asarray([v], jnp.uint32))
                 model[k] = v & 0xFFFFFFFF
             else:
-                t, _ = sv.erase(t, ka)
+                t, _ = sv_erase(t, ka)
                 model.pop(k, None)
         blob = snapshot.snapshot_bytes(t)
         frozen = dict(model)
         # post-snapshot mutations that must NOT leak into the restore
         for k in list(model)[: len(model) // 2]:
-            t, _ = sv.erase(t, jnp.asarray([k], jnp.uint32))
-        t, _ = sv.insert(t, jnp.asarray([41], jnp.uint32),
+            t, _ = sv_erase(t, jnp.asarray([k], jnp.uint32))
+        t, _ = sv_insert(t, jnp.asarray([41], jnp.uint32),
                          jnp.asarray([0], jnp.uint32))
         restored = snapshot.restore_bytes(blob)
         assert int(restored.count) == len(frozen)
         universe = jnp.arange(1, 42, dtype=jnp.uint32)
-        got, found = sv.retrieve(restored, universe)
+        got, found = sv_retrieve(restored, universe)
         for i, k in enumerate(range(1, 42)):
             assert bool(found[i]) == (k in frozen)
             if k in frozen:
@@ -638,9 +697,9 @@ class TestSnapshotProperties:
         for op, k, v in ops:
             ka = jnp.asarray([k], jnp.uint32)
             if op == "insert":
-                t, _ = sv.insert(t, ka, jnp.asarray([v], jnp.uint32))
+                t, _ = sv_insert(t, ka, jnp.asarray([v], jnp.uint32))
             else:
-                t, _ = sv.erase(t, ka)
+                t, _ = sv_erase(t, ka)
         assert snapshot.snapshot_bytes(t) == snapshot.snapshot_bytes(t)
 
 
@@ -652,35 +711,29 @@ class TestShardedBloomInvariant:
     @SETTINGS
     @given(ops=ops_st(), num_shards=st.sampled_from([2, 4]))
     def test_live_keys_always_advertised(self, ops, num_shards):
-        from repro.serving import elastic
         st_ = elastic.create(num_shards, 512, window=16)
         model = {}
         compact_every = 7
         for i, (op, k, v) in enumerate(ops):
             ka = jnp.asarray([k], jnp.uint32)
             if op == "insert":
-                st_, _ = elastic.insert(st_, ka,
-                                        jnp.asarray([v], jnp.uint32))
+                st_, _ = el_insert(st_, ka, jnp.asarray([v], jnp.uint32))
                 model[k] = v & 0xFFFFFFFF
             else:
-                st_, _ = elastic.erase(st_, ka)
+                st_, _ = el_erase(st_, ka)
                 model.pop(k, None)
             if i % compact_every == compact_every - 1:
-                st_ = elastic.compact_all(st_)   # filter rebuild point
+                st_ = el_compact_all(st_)        # filter rebuild point
+                assert int(elastic.count(st_)) == len(model)
             if not model:
                 continue
-            live = jnp.asarray(sorted(model), jnp.uint32)
-            words = sv.key_hash_word(
-                sv.normalize_key_batch(live, 1, "keys"))
-            owners = hashing.hash_owner(words, num_shards)
-            bits = jnp.stack([f.bits for f in st_.filters])
-            admitted = bf.contains_stack(st_.filters[0], bits, owners,
-                                         words)
+            live, _ = _pad(sorted(model), 40)   # ops_st's universe
+            admitted = _advertised(st_.filters, live)[:len(model)]
             assert bool(jnp.all(admitted)), \
                 "live key not advertised by its owner's filter"
         # and the lookup path agrees with the dict model end-to-end
         universe = jnp.arange(1, 41, dtype=jnp.uint32)
-        got, found, stats = elastic.lookup(st_, universe)
+        got, found, stats = el_lookup(st_, universe)
         assert int(stats["overflow"]) == 0
         for i, k in enumerate(range(1, 41)):
             assert bool(found[i]) == (k in model)
@@ -693,13 +746,14 @@ class TestShardedBloomInvariant:
         """rebuild_from_table never advertises MORE than the incremental
         filter: rebuilt bits are a subset (erase-staleness only shrinks)."""
         t = sv.create(512, window=16)
-        ka = jnp.asarray(np.unique(np.asarray(keys, np.uint32)))
-        t, _ = sv.insert(t, ka, ka)
-        f_inc = bf.insert(bf.create(1 << 12), sv.key_hash_word(
-            sv.normalize_key_batch(ka, 1, "keys")))
-        half = ka[: ka.shape[0] // 2]
-        if half.shape[0]:
-            t, _ = sv.erase(t, half)
+        u = np.unique(np.asarray(keys, np.uint32))
+        ka, live = _pad(u, KEYS_MAX)
+        t, _ = sv_insert(t, ka, ka, live)
+        f_inc = bf_insert(bf.create(1 << 12), sv.key_hash_word(
+            sv.normalize_key_batch(ka, 1, "keys")), live)
+        half = jnp.arange(KEYS_MAX) < len(u) // 2
+        if len(u) // 2:
+            t, _ = sv_erase(t, ka, half)
         f_reb = bf.rebuild_from_table(f_inc, t)
         assert bool(jnp.all(f_inc.bits >= f_reb.bits)), \
             "rebuilt filter set a bit the incremental filter never did"

@@ -336,7 +336,7 @@ class TestLazyImportInsideJit:
             print('OK')
         """)
         r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                           text=True, timeout=300, env=env,
+                           text=True, timeout=100, env=env,
                            cwd=os.path.dirname(os.path.dirname(
                                os.path.abspath(__file__))))
         assert r.returncode == 0, f"STDERR:\n{r.stderr[-3000:]}"
@@ -353,6 +353,9 @@ class TestShardedJoin:
             from collections import defaultdict
             from repro.relational import join
             mesh = jax.make_mesh((8,), ('x',))
+            # jitted: an eager shard_map compiles each primitive on its own
+            shard_join = jax.jit(join.shard_join, static_argnums=(0, 1, 4, 5),
+                                 static_argnames='slack')
             rng = np.random.default_rng(0)
             bk = rng.integers(1, 300, 8 * 64).astype(np.uint32)
             pk = rng.integers(1, 400, 8 * 128).astype(np.uint32)
@@ -362,8 +365,8 @@ class TestShardedJoin:
             refm = np.array([int(k) in d for k in pk])
             ref = sorted((i, j) for j, k in enumerate(pk)
                          for i in d.get(int(k), []))
-            out = join.shard_join(mesh, 'x', jnp.asarray(bk),
-                                  jnp.asarray(pk), 2048, 'inner', slack=4.0)
+            out = shard_join(mesh, 'x', jnp.asarray(bk),
+                             jnp.asarray(pk), 2048, 'inner', slack=4.0)
             assert int(np.asarray(out['overflow']).sum()) == 0
             got = sorted((int(b), int(p)) for b, p, v in
                          zip(out['build_idx'], out['probe_idx'],
@@ -372,8 +375,8 @@ class TestShardedJoin:
             assert (np.asarray(out['matched']) == refm).all()
             for how, expect in (('semi', int(refm.sum())),
                                 ('anti', int((~refm).sum()))):
-                o = join.shard_join(mesh, 'x', jnp.asarray(bk),
-                                    jnp.asarray(pk), 2048, how, slack=4.0)
+                o = shard_join(mesh, 'x', jnp.asarray(bk),
+                               jnp.asarray(pk), 2048, how, slack=4.0)
                 assert int(np.asarray(o['total']).sum()) == expect, how
             # composite two-column keys ride the same ownership exchange:
             # (hi, lo) tuples vs the equivalent u32-packed single words.
@@ -383,14 +386,14 @@ class TestShardedJoin:
             # input-aligned matched mask, and the total
             bh, bl = bk >> 4, (bk & 15) | 1
             ph, plo = pk >> 4, (pk & 15) | 1
-            oc = join.shard_join(mesh, 'x',
-                                 (jnp.asarray(bh), jnp.asarray(bl)),
-                                 (jnp.asarray(ph), jnp.asarray(plo)),
-                                 2048, 'inner', slack=4.0)
-            op = join.shard_join(mesh, 'x',
-                                 jnp.asarray((bh << 4) | bl),
-                                 jnp.asarray((ph << 4) | plo),
-                                 2048, 'inner', slack=4.0)
+            oc = shard_join(mesh, 'x',
+                            (jnp.asarray(bh), jnp.asarray(bl)),
+                            (jnp.asarray(ph), jnp.asarray(plo)),
+                            2048, 'inner', slack=4.0)
+            op = shard_join(mesh, 'x',
+                            jnp.asarray((bh << 4) | bl),
+                            jnp.asarray((ph << 4) | plo),
+                            2048, 'inner', slack=4.0)
             def pairs(o):
                 return sorted((int(b), int(p)) for b, p, v in
                               zip(o['build_idx'], o['probe_idx'],
@@ -404,7 +407,7 @@ class TestShardedJoin:
             print('OK')
         """)
         r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                           text=True, timeout=540, env=env,
+                           text=True, timeout=100, env=env,
                            cwd=os.path.dirname(os.path.dirname(
                                os.path.abspath(__file__))))
         assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr[-3000:]}"

@@ -101,6 +101,9 @@ def test_alias_parser_on_minimal_donated_fn():
     assert all(a["param_index"] == () for a in aliases)
 
 
+# the eager reference recompiles every stage for each of its 12 chunks
+# (65 s alone, 112 s in a full run under six xdist workers)
+@pytest.mark.time_limit(300)
 def test_stream_bit_exact_vs_eager_across_compaction():
     cfg = _churn_cfg()
     chunks, watch = _workload(cfg, 12, vocab=24, seed=3)

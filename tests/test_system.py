@@ -117,12 +117,13 @@ def test_train_driver_end_to_end(tmp_path):
             "smollm-360m", "--smoke", "--batch", "4", "--seq", "32",
             "--ckpt-dir", str(tmp_path), "--ckpt-every", "5",
             "--log-every", "5", "--lr", "3e-3"]
+    # two runs, each well inside the test's time limit (root conftest.py)
     r = subprocess.run(base + ["--steps", "10"], capture_output=True,
-                       text=True, timeout=500, env=env)
+                       text=True, timeout=80, env=env)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "step 9" in r.stdout
     r2 = subprocess.run(base + ["--steps", "14", "--resume"],
-                        capture_output=True, text=True, timeout=500, env=env)
+                        capture_output=True, text=True, timeout=80, env=env)
     assert r2.returncode == 0, r2.stderr[-2000:]
     assert "resumed from step 10" in r2.stdout
 
@@ -132,6 +133,6 @@ def test_serve_driver_end_to_end():
     r = subprocess.run([sys.executable, "-m", "repro.launch.serve",
                         "--arch", "smollm-360m", "--smoke", "--batch", "2",
                         "--prompt-len", "8", "--max-new", "8"],
-                       capture_output=True, text=True, timeout=500, env=env)
+                       capture_output=True, text=True, timeout=100, env=env)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "tok/s" in r.stdout
