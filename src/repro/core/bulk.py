@@ -300,6 +300,26 @@ def _segmented_combine(vals, reset, combine):
 # step 2 — vectorized probe walk (batch version of _probe_for_insert)
 # ---------------------------------------------------------------------------
 
+#: each buffer of the compacted tail holds 1/_SHRINK of its parent's lanes.
+#: At load 0.9 about 4% of the keys need a second window and 0.8% a third
+#: (sv_lookup's key set): a sixteenth holds the first, and the second
+#: overflows into a few cheap rounds.  On one v5e chip a 2^24-key lookup
+#: took 1.560 s with 16 and 1.990 s with 4.
+_SHRINK = 16
+
+
+def _tail_sizes(n):
+    """Buffer sizes of the compacted tail for a batch of ``n`` (static):
+    n/16, n/256, ... while at least ``probing.COMPACT_LANES``; empty when
+    the batch is too small to compact."""
+    sizes = []
+    m = n // _SHRINK
+    while m >= probing.COMPACT_LANES:
+        sizes.append(m)
+        m //= _SHRINK
+    return tuple(sizes)
+
+
 def probe_matches(tstatic, store, keys, words, active, count=None,
                   stats=False):
     """One COPS walk for every active element against the current store.
@@ -312,26 +332,41 @@ def probe_matches(tstatic, store, keys, words, active, count=None,
     bulk-build-from-fresh case), the walk is skipped: an empty table can
     hold no match even if erases left tombstones behind.
 
-    ``stats`` (static) additionally carries a per-element probe-length
-    counter — windows examined before the element's walk stopped — and
-    returns it as a fourth output.  When False (default) the traced graph
-    is exactly the three-output walk (byte-identical HLO).
+    **Compacted tail.**  Most keys stop in their first window (1.05
+    windows per key at load 0.9), but a full-width loop runs until the
+    slowest key stops.  So when the budget exceeds
+    ``probing.UNROLL_PROBES`` and the batch holds at least 16 times
+    ``probing.COMPACT_LANES`` elements, window 1 runs over the whole
+    batch, and the keys still pending after it move into a static buffer
+    of 1/16 of the batch (pending lanes first, in batch order, by one sort
+    of their indices).  That buffer runs window 2, its pending keys move
+    into a buffer of 1/256, and so on down to the last buffer of at least
+    ``COMPACT_LANES`` lanes, which runs the while-loop to the end.  Each buffer's results scatter back to its parent by index.  A
+    stage whose pending keys outnumber its buffer takes them in rounds,
+    each round walking its keys to the end before the next; nothing is
+    dropped or cut short.  The result is exact: every key's walk reads the
+    same immutable store, visits the same rows in the same order and stops
+    on the same rule whichever lanes share its window, so (matched, row,
+    lane) and the probe length equal the full-width loop's.
+
+    ``stats`` (static) additionally returns the per-element probe length
+    — windows examined before the element's walk stopped — and the number
+    of compacted rounds run (0 on the full-width paths) as a fourth and
+    fifth output.  When False (default) the graph carries neither.
     """
     ops, scheme, seed, max_probes = tstatic
     num_rows, w = ops.num_rows, ops.window
     n = keys.shape[0]
     row0 = probing.initial_row(words, num_rows, seed, ops.quotient)
     step = probing.row_step(scheme, words, num_rows, seed, ops.quotient)
+    zero = jnp.zeros((), _I)
 
     def empty(_):
         out = (jnp.zeros((n,), bool), row0, jnp.zeros((n,), _U))
-        return out + ((jnp.zeros((n,), _I),) if stats else ())
+        return out + ((jnp.zeros((n,), _I), zero) if stats else ())
 
-    def walk(_):
-        def cond(st):
-            attempt, row, done, *_ = st
-            return jnp.logical_and(attempt < max_probes, ~jnp.all(done))
-
+    def window(keys, words, step):
+        """Loop body: one probe window for every lane not yet done."""
         def body(st):
             if stats:
                 attempt, row, done, mrow, mlane, matched, plen = st
@@ -358,11 +393,64 @@ def probe_matches(tstatic, store, keys, words, active, count=None,
             out = (attempt + 1, jnp.where(done, row, nrow), done, mrow,
                    mlane, matched)
             return out + ((plen,) if stats else ())
+        return body
 
-        z = jnp.zeros((n,), _U)
-        st = (jnp.zeros((), _I), row0, ~active, z, z, jnp.zeros((n,), bool))
-        if stats:
-            st = st + (jnp.zeros((n,), _I),)
+    def cond(st):
+        attempt, row, done, *_ = st
+        return jnp.logical_and(attempt < max_probes, ~jnp.all(done))
+
+    def start(attempt, row, done):
+        """Walk state of lanes that have run ``attempt`` windows unmatched."""
+        m = row.shape[0]
+        z = jnp.zeros((m,), _U)
+        st = (jnp.asarray(attempt, _I), row, done, z, z,
+              jnp.zeros((m,), bool))
+        return st + ((jnp.full((m,), attempt, _I),) if stats else ())
+
+    def tail(k, sizes, keys, words, step, st):
+        """Finish the walks of a buffer whose lanes have all run ``k``
+        windows.  Returns the state, plus the rounds run under stats."""
+        if k >= max_probes or not sizes:
+            if k < max_probes:
+                st = jax.lax.while_loop(cond, window(keys, words, step), st)
+            return (st, zero) if stats else (st,)
+        big, m, kw = st[1].shape[0], sizes[0], keys.shape[1]
+        payload = jnp.concatenate([keys, words[:, None], st[1][:, None]],
+                                  axis=1)
+        lane = jnp.arange(big, dtype=_U)
+
+        def put(a, sel, b):
+            return a.at[sel].set(b, mode="drop", indices_are_sorted=True,
+                                 unique_indices=True)
+
+        def rbody(c):
+            st = c[0]
+            # pending lanes first, in batch order; the others sort past
+            # ``big``, so the indices are sorted and distinct, and those
+            # past ``big`` fill (gather) or drop (scatter)
+            sel = jnp.sort(jnp.where(st[2], _U(big) + lane, lane))[:m]
+            sub = payload.at[sel].get(mode="fill", fill_value=0,
+                                      indices_are_sorted=True,
+                                      unique_indices=True)
+            ckeys, cwords = sub[:, :kw], sub[:, kw]
+            cstep = probing.row_step(scheme, cwords, num_rows, seed,
+                                     ops.quotient)
+            cst = window(ckeys, cwords, cstep)(
+                start(k, sub[:, kw + 1], sel >= big))
+            cres = tail(k + 1, sizes[1:], ckeys, cwords, cstep, cst)
+            cst = cres[0]
+            st = st[:2] + (put(st[2], sel, jnp.ones((m,), bool)),) + tuple(
+                put(a, sel, b) for a, b in zip(st[3:], cst[3:]))
+            return (st,) + ((c[1] + 1 + cres[1],) if stats else ())
+
+        c = (st,) + ((zero,) if stats else ())
+        return jax.lax.while_loop(lambda c: ~jnp.all(c[0][2]), rbody, c)
+
+    def walk(_):
+        st = start(0, row0, ~active)
+        body = window(keys, words, step)
+        sizes = _tail_sizes(n)
+        rounds = zero
         if max_probes <= probing.UNROLL_PROBES:
             # static <= 2-window budget (the bucketed walk): unroll so the
             # walk costs the same at every load factor; body is a no-op
@@ -370,11 +458,15 @@ def probe_matches(tstatic, store, keys, words, active, count=None,
             res = st
             for _ in range(max_probes):
                 res = body(res)
+        elif sizes:
+            out = tail(1, sizes, keys, words, step, body(st))
+            res = out[0]
+            rounds = out[1] if stats else zero
         else:
             res = jax.lax.while_loop(cond, body, st)
         matched, mrow, mlane = res[5], res[3], res[4]
         if stats:
-            return matched, mrow, mlane, res[6]
+            return matched, mrow, mlane, res[6], rounds
         return matched, mrow, mlane
 
     if count is None:
@@ -593,12 +685,13 @@ def _walk_plen(matched, probe_plen, claim_attempt, max_probes):
                      jnp.clip(claim_attempt, 0, max_probes))
 
 
-def _build_stats(table, status, plen, active, sweeps):
+def _build_stats(table, status, plen, active, sweeps, tail_rounds=None):
     """Assemble the in-graph TableStats for a build op (post-op table)."""
     from repro.obs import metrics
     return metrics.table_stats(table.ops, table.store, status=status,
                                plen=plen, active=active,
-                               fixpoint_iters=sweeps)
+                               fixpoint_iters=sweeps,
+                               tail_rounds=tail_rounds)
 
 
 def _finish_fast(table, keys, live, is_rep, rep_of, matched, mrow, mlane,
@@ -649,7 +742,8 @@ def insert_single(table, keys, values, mask=None, stats=False):
         return out
     ntable, status = out
     plen = _walk_plen(matched, pm[3], pc[4], tstat[3])
-    return ntable, status, _build_stats(ntable, status, plen, is_rep, pc[5])
+    return ntable, status, _build_stats(ntable, status, plen, is_rep, pc[5],
+                                        pm[4])
 
 
 def update_single(table, keys, update_fn, combine, init, values, mask=None,
@@ -716,7 +810,8 @@ def update_single(table, keys, update_fn, combine, init, values, mask=None,
         return out
     ntable, status = out
     plen = _walk_plen(matched, pm[3], pc[4], tstat[3])
-    return ntable, status, _build_stats(ntable, status, plen, is_rep, pc[5])
+    return ntable, status, _build_stats(ntable, status, plen, is_rep, pc[5],
+                                        pm[4])
 
 
 def insert_multi(table, keys, values, mask=None, stats=False):
@@ -789,7 +884,8 @@ def _insert_general(table, tstat, keys, values, mask, stats=False):
     if not stats:
         return ntable, status
     plen = _walk_plen(matched, pm[3], pc[4], tstat[3])
-    return ntable, status, _build_stats(ntable, status, plen, is_rep, pc[5])
+    return ntable, status, _build_stats(ntable, status, plen, is_rep, pc[5],
+                                        pm[4])
 
 
 def _update_general(table, tstat, keys, update_fn, combine, init, values,
@@ -834,4 +930,5 @@ def _update_general(table, tstat, keys, update_fn, combine, init, values,
     if not stats:
         return ntable, status
     plen = _walk_plen(matched, pm[3], pc[4], tstat[3])
-    return ntable, status, _build_stats(ntable, status, plen, is_rep, pc[5])
+    return ntable, status, _build_stats(ntable, status, plen, is_rep, pc[5],
+                                        pm[4])

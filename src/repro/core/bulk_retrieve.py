@@ -45,6 +45,19 @@ occupied-mask.  Any store that renders those three rides this engine's
 walk + compaction unchanged: open-addressing layouts expose
 ``row * window + lane``, the bucket-list table its value pool.
 
+**The single-value walk.**  ``retrieve_single``, ``contains_single`` and
+``erase_single`` locate their representatives with the build engine's
+match walk, ``bulk.probe_matches``: a single-value key has one slot, so
+they need no counts and no arena.  A large batch walks in stages there:
+window 1 over the whole batch, then the keys still pending move into
+buffers of n/16, n/256, ... (down to ``probing.COMPACT_LANES`` lanes), each
+of which runs one window, hands its own pending keys on, and scatters its
+results back by index; the last buffer runs the loop to the end, and a
+buffer whose pending keys outnumber it takes them in rounds.  Results are
+exact: every key reads the same immutable store, visits the same rows and
+stops on the same rule whichever lanes share its window.  ``fused_walk``
+below still runs every element to the slowest one's end (ROADMAP).
+
 **The revisit-free guard** (``fused_ok``): the arena holds at most one
 (query, rank) pair per slot, so the fused gather/erase path requires
 walks that never visit a probe row twice — cops/linear with
@@ -346,11 +359,11 @@ def _emit_store(table, out_capacity, counts, is_rep, rep_of, rcnt, qarena,
 # multi-value entry points
 # ---------------------------------------------------------------------------
 
-def _retrieval_stats(table, plen=None, active=None):
+def _retrieval_stats(table, plen=None, active=None, tail_rounds=None):
     """TableStats for a pure retrieval walk (no statuses, no fixpoint)."""
     from repro.obs import metrics
     return metrics.table_stats(table.ops, table.store, plen=plen,
-                               active=active)
+                               active=active, tail_rounds=tail_rounds)
 
 
 def count_multi(table, keys, mask=None, stats=False):
@@ -436,7 +449,7 @@ def _locate_reps(table, keys, stats=False):
         stats=stats)
     matched, mrow, mlane = pm[:3]
     out = (is_rep, rep_of, matched, mrow, mlane)
-    return out + ((pm[3],) if stats else ())
+    return out + (pm[3:] if stats else ())
 
 
 def retrieve_single(table, keys, stats=False):
@@ -458,7 +471,8 @@ def retrieve_single(table, keys, stats=False):
     vals = jnp.where(found[:, None], rvals[rep_of], 0)
     res = ((vals[:, 0] if vw == 1 else vals), found)
     if stats:
-        return res + (_retrieval_stats(table, plen=lr[5], active=is_rep),)
+        return res + (_retrieval_stats(table, plen=lr[5], active=is_rep,
+                                       tail_rounds=lr[6]),)
     return res
 
 

@@ -70,6 +70,12 @@ SCHEMES = ("cops", "linear", "quadratic", "bucketed")
 #: factor, which is what keeps its retrieve throughput flat in rho
 UNROLL_PROBES = 2
 
+#: smallest buffer of the probe walk's compacted tail (``bulk.
+#: probe_matches``): the keys still pending after window 1 move into a
+#: buffer of 1/16 of the batch, then 1/256, ... while a buffer keeps at
+#: least this many lanes; batches under 16 times this walk at full width
+COMPACT_LANES = 1024
+
 
 def scheme_coverage(scheme: str, num_rows: int) -> int:
     """Number of DISTINCT rows a scheme's walk can ever reach (static).

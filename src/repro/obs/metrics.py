@@ -26,6 +26,10 @@ Conventions
   statuses and leave it zero.
 - **fixpoint_iters** counts virtual-fill arbitration sweeps
   (``bulk.place_claims``) — 0 for ops that never place.
+- **tail_rounds** counts the compacted rounds of the probe walk
+  (``bulk.probe_matches``): one per buffer entered, more where a buffer
+  overflows; 0 when every key stops in its first window or the batch is
+  too small to compact.
 - **live/tombstone slots + load factor** are a census of key plane 0 of
   the post-op store: exactly the signals a growth/compaction policy
   triggers on (ROADMAP).
@@ -69,6 +73,7 @@ class TableStats:
     probe_sum: jax.Array             # i32 — sum of probe lengths
     probe_n: jax.Array               # i32 — number of walking elements
     fixpoint_iters: jax.Array        # i32 — arbitration sweeps
+    tail_rounds: jax.Array           # i32 — compacted probe-walk rounds
     live_slots: jax.Array            # i32
     tombstone_slots: jax.Array       # i32
     load_factor: jax.Array           # f32 — live / capacity
@@ -96,6 +101,7 @@ class TableStats:
             "probe_len_p50": self.probe_quantile(0.50),
             "probe_len_p99": self.probe_quantile(0.99),
             "fixpoint_iters": int(self.fixpoint_iters),
+            "tail_rounds": int(self.tail_rounds),
             "live_slots": int(self.live_slots),
             "tombstone_slots": int(self.tombstone_slots),
             "load_factor": float(self.load_factor),
@@ -107,7 +113,7 @@ def empty() -> TableStats:
     return TableStats(
         status_hist=jnp.zeros((NUM_STATUS,), _I),
         probe_hist=jnp.zeros((NUM_PROBE_BINS,), _I),
-        probe_sum=z, probe_n=z, fixpoint_iters=z,
+        probe_sum=z, probe_n=z, fixpoint_iters=z, tail_rounds=z,
         live_slots=z, tombstone_slots=z, load_factor=jnp.zeros((), _F))
 
 
@@ -175,7 +181,7 @@ def slot_stats(ops, store):
 
 
 def table_stats(ops, store, *, status=None, plen=None, active=None,
-                fixpoint_iters=None) -> TableStats:
+                fixpoint_iters=None, tail_rounds=None) -> TableStats:
     """Assemble a ``TableStats`` from whatever an op measured.
 
     ``store`` is the *post-op* store (slot census); any of the walk-level
@@ -191,9 +197,11 @@ def table_stats(ops, store, *, status=None, plen=None, active=None,
         ph, ps, pn = st.probe_hist, st.probe_sum, st.probe_n
     fx = st.fixpoint_iters if fixpoint_iters is None else \
         jnp.asarray(fixpoint_iters, _I)
+    tr = st.tail_rounds if tail_rounds is None else \
+        jnp.asarray(tail_rounds, _I)
     return TableStats(status_hist=sh, probe_hist=ph, probe_sum=ps,
-                      probe_n=pn, fixpoint_iters=fx, live_slots=live,
-                      tombstone_slots=tomb, load_factor=lf)
+                      probe_n=pn, fixpoint_iters=fx, tail_rounds=tr,
+                      live_slots=live, tombstone_slots=tomb, load_factor=lf)
 
 
 def merge(a: TableStats, b: TableStats) -> TableStats:
@@ -205,6 +213,7 @@ def merge(a: TableStats, b: TableStats) -> TableStats:
         probe_sum=a.probe_sum + b.probe_sum,
         probe_n=a.probe_n + b.probe_n,
         fixpoint_iters=a.fixpoint_iters + b.fixpoint_iters,
+        tail_rounds=a.tail_rounds + b.tail_rounds,
         live_slots=b.live_slots, tombstone_slots=b.tombstone_slots,
         load_factor=b.load_factor)
 
@@ -221,9 +230,8 @@ def measure_probe_lengths(tstatic, store, keys, active,
     from repro.core import single_value as sv
     if words is None:
         words = sv.key_hash_word(keys)
-    _, _, _, plen = bulk.probe_matches(tstatic, store, keys, words, active,
-                                       stats=True)
-    return plen
+    return bulk.probe_matches(tstatic, store, keys, words, active,
+                              stats=True)[3]
 
 
 def bolt_on_stats(table, keys, status=None, mask=None) -> TableStats:

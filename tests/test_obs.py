@@ -24,6 +24,7 @@ import numpy as np
 import pytest
 
 from repro.core import bucket_list as bl
+from repro.core import bulk
 from repro.core import counting, probing
 from repro.core import multi_value as mv
 from repro.core import single_value as sv
@@ -261,6 +262,22 @@ class TestHloIdentity:
             lambda t, k: mv.retrieve_all(t, k, cap, stats=False), t0, keys)
         on = _compiled_text(
             lambda t, k: mv.retrieve_all(t, k, cap, stats=True), t0, keys)
+        assert default == off
+        assert default != on
+        ca, cb = hlo_census.census(default), hlo_census.census(off)
+        assert ca.bytes_moved == cb.bytes_moved and ca.flops == cb.flops
+
+    def test_compacted_retrieve_stats_off_identical(self, rng):
+        # a batch large enough for the probe walk's compacted tail
+        n = bulk._SHRINK * probing.COMPACT_LANES
+        keys, vals = _keys_vals(rng, n)
+        t0 = sv.create(n + n // 8, window=8)
+        t0, _ = sv.insert(t0, keys, vals)
+        default = _compiled_text(lambda t, k: sv.retrieve(t, k), t0, keys)
+        off = _compiled_text(lambda t, k: sv.retrieve(t, k, stats=False),
+                             t0, keys)
+        on = _compiled_text(lambda t, k: sv.retrieve(t, k, stats=True),
+                            t0, keys)
         assert default == off
         assert default != on
         ca, cb = hlo_census.census(default), hlo_census.census(off)

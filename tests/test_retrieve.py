@@ -7,17 +7,23 @@ overflow truncation, u64 (2-word) keys, empty batches and ``n=0`` /
 ``out_capacity=0`` edges.  The Pallas walk tile joins the same contract
 where the kernel path applies."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import bulk
 from repro.core import bulk_retrieve as br
 from repro.core import counting as ct
 from repro.core import hashset as hs
 from repro.core import multi_value as mv
+from repro.core import probing
 from repro.core import single_value as sv
 from repro.relational import join
+
+from conftest import unique_keys
 
 
 def _pair(create_fn, **kw):
@@ -375,3 +381,163 @@ class TestJoinLeftOuterTruncation:
                         (rj.probe_idx, rs.probe_idx),
                         (rj.valid, rs.valid), (rj.matched, rs.matched),
                         (rj.total, rs.total))
+
+
+# ---------------------------------------------------------------------------
+# compacted probe walk: batches of at least 16 x COMPACT_LANES keys walk
+# window 1 at full width, then shrinking buffers of the pending keys
+# ---------------------------------------------------------------------------
+
+N_COMPACT = bulk._SHRINK * probing.COMPACT_LANES   # smallest that compacts
+
+
+def _compact_table(n_pairs, load=0.9, window=32, max_probes=None, seed=0,
+                   erase_share=0.0):
+    """A jax-backend table of ``n_pairs`` distinct keys at ``load``, with the
+    first ``erase_share`` of them erased again (tombstones), and as many
+    distinct keys that were never inserted."""
+    rng = np.random.default_rng(seed)
+    ks = (rng.choice(2 ** 31, 2 * n_pairs, replace=False) + 1).astype(
+        np.uint32)
+    present, absent = ks[:n_pairs], ks[n_pairs:]
+    t = sv.create(int(n_pairs / load), window=window, max_probes=max_probes)
+    t, _ = jax.jit(sv.insert)(t, jnp.asarray(present),
+                              jnp.asarray(present * 3))
+    gone = present[:int(n_pairs * erase_share)]
+    if gone.size:
+        t, _ = jax.jit(sv.erase)(t, jnp.asarray(gone))
+    return t, present, absent, rng
+
+
+def _compact_queries(case):
+    """(table, queries) of one parity case; every batch compacts."""
+    n = N_COMPACT
+    if case == "present":
+        t, p, _, rng = _compact_table(2 * n)
+        q = rng.permutation(p)
+    elif case == "absent90":
+        t, p, a, rng = _compact_table(2 * n)
+        q = rng.permutation(np.concatenate([p[:n // 10], a[:n - n // 10]]))
+    elif case == "duplicates":
+        t, p, a, rng = _compact_table(n)
+        q = np.concatenate([rng.choice(p, 3 * n // 4), a[:n // 4]])
+    elif case == "tombstones":
+        t, p, a, rng = _compact_table(n, load=0.95, erase_share=0.4)
+        q = rng.permutation(np.concatenate([p, a[:n // 2]]))
+    elif case.startswith("max_probes"):
+        t, p, a, rng = _compact_table(n, max_probes=int(case[-1]))
+        q = rng.permutation(np.concatenate([p, a[:n // 4]]))
+    else:                                  # "w4" / "w8": stages overflow
+        t, p, a, rng = _compact_table(n, load=0.95, window=int(case[1:]))
+        q = rng.permutation(np.concatenate([p, a[:n // 2]]))
+    return t, jnp.asarray(q)
+
+
+@pytest.fixture
+def full_width(monkeypatch):
+    """Trace and run ``fn`` with the compacted tail switched off: no buffer
+    is large enough, so every walk is the full-width loop.  A fresh
+    wrapper keeps jit from reusing a trace of the compacted walk."""
+    def run(fn, *args):
+        with monkeypatch.context() as m:
+            m.setattr(probing, "COMPACT_LANES", 1 << 40)
+            return jax.jit(lambda *a: fn(*a))(*args)
+    return run
+
+
+def _walk(t, q):
+    """(matched, row, lane, plen, tail_rounds) of the representatives'
+    walk, as ``retrieve`` runs it."""
+    def fn(t, q):
+        kb = sv.normalize_key_batch(q, 1, "keys")
+        is_rep, _ = br.group_queries(kb, jnp.ones(q.shape, bool))
+        return bulk.probe_matches(bulk._tstatic(t), t.store, kb,
+                                  sv.probe_words(t, kb), is_rep, t.count,
+                                  stats=True)
+    return jax.jit(fn)(t, q)
+
+
+class TestCompactedWalkParity:
+    @pytest.mark.parametrize("case", [
+        "present", "absent90", "duplicates", "tombstones", "max_probes1",
+        "max_probes2", "max_probes3", "w4", "w8"])
+    def test_matches_full_width_and_scan(self, case, full_width):
+        t, q = _compact_queries(case)
+        assert q.shape[0] >= N_COMPACT
+        walk = _walk(t, q)
+        ref = full_width(_walk, t, q)
+        assert_same(*zip(walk[:4], ref[:4]))           # matched, row, lane, plen
+        stats_fn = lambda t, q: sv.retrieve(t, q, stats=True)
+        vj, fj, sj = jax.jit(stats_fn)(t, q)
+        vf, ff, sf = full_width(stats_fn, t, q)
+        vs, fs = jax.jit(sv.retrieve)(dataclasses.replace(t, backend="scan"), q)
+        assert_same((vj, vs), (fj, fs), (vj, vf), (fj, ff),
+                    (sj.probe_hist, sf.probe_hist),
+                    (sj.probe_sum, sf.probe_sum), (sj.probe_n, sf.probe_n))
+        assert_same((sv.contains(t, q), fs))
+        assert int(sf.tail_rounds) == 0
+        assert int(sj.tail_rounds) == int(walk[4])
+
+    def test_erase_with_duplicates_and_mask(self, full_width):
+        t, p, a, rng = _compact_table(N_COMPACT)
+        q = jnp.asarray(np.concatenate([rng.choice(p, N_COMPACT // 2),
+                                        a[:N_COMPACT // 2]]))
+        m = jnp.asarray(rng.random(N_COMPACT) < 0.7)
+        tj, ej = jax.jit(sv.erase)(t, q, m)
+        tf, ef = full_width(sv.erase, t, q, m)
+        ts, es = jax.jit(sv.erase)(dataclasses.replace(t, backend="scan"),
+                                   q, m)
+        assert_same((ej, es), (ej, ef))
+        assert_stores_equal(tj, ts)
+        assert_stores_equal(tj, tf)
+
+    def test_insert_into_filled_table_with_mask(self, full_width):
+        """Upserts into a non-empty table walk for their matches first."""
+        t, p, a, rng = _compact_table(N_COMPACT, load=0.8)
+        q = jnp.asarray(np.concatenate([p[:N_COMPACT // 2],
+                                        a[:N_COMPACT // 2]]))
+        m = jnp.asarray(rng.random(N_COMPACT) < 0.8)
+        ins = lambda t, k, v, m: sv.insert(t, k, v, m, stats=True)
+        tj, sj, stj = jax.jit(ins)(t, q, q + 7, m)
+        tf, sf, stf = full_width(ins, t, q, q + 7, m)
+        assert_same((sj, sf), (stj.probe_hist, stf.probe_hist),
+                    (stj.probe_sum, stf.probe_sum))
+        assert_stores_equal(tj, tf)
+        assert int(stj.tail_rounds) > 0 and int(stf.tail_rounds) == 0
+
+
+class TestTailRounds:
+    def test_zero_when_every_key_stops_in_window_one(self):
+        rng = np.random.default_rng(21)
+        keys = jnp.asarray(unique_keys(rng, N_COMPACT))
+        t = sv.create(8 * N_COMPACT, window=32)          # load 1/8
+        t, _ = jax.jit(sv.insert)(t, keys, keys)
+        st = jax.jit(lambda t, k: sv.retrieve(t, k, stats=True))(t, keys)[2]
+        assert int(st.probe_hist[0]) == N_COMPACT
+        assert int(st.tail_rounds) == 0
+
+    def test_zero_below_the_size_threshold(self):
+        t, p, _, _ = _compact_table(N_COMPACT)
+        q = jnp.asarray(p[:N_COMPACT - 1])
+        walk = _walk(t, q)
+        assert int(jnp.max(walk[3])) > 1                 # keys took window 2+
+        assert int(walk[4]) == 0
+
+    @pytest.mark.parametrize("n_pairs,load,stages", [
+        (4 * N_COMPACT, 0.9, 1), (16 * N_COMPACT, 0.8, 2)])
+    def test_one_round_per_stage(self, n_pairs, load, stages):
+        t, p, _, _ = _compact_table(n_pairs, load=load)
+        walk = _walk(t, jnp.asarray(p))
+        sizes = bulk._tail_sizes(n_pairs)
+        assert len(sizes) == stages
+        assert int(jnp.max(walk[3])) > stages + 1        # every one entered
+        for k, m in enumerate(sizes, start=1):           # none overflows
+            assert int(jnp.sum(walk[3] > k)) <= m
+        assert int(walk[4]) == stages
+
+    def test_more_rounds_when_a_stage_overflows(self):
+        t, q = _compact_queries("w4")
+        walk = _walk(t, q)
+        pending = int(jnp.sum(walk[3] > 1))              # after window 1
+        assert pending > bulk._tail_sizes(q.shape[0])[0]
+        assert int(walk[4]) > len(bulk._tail_sizes(q.shape[0]))
