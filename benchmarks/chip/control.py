@@ -11,8 +11,10 @@ line per side with every number a run compares:
 - ``control``: the library's own bounded-probing path switched on
   (``max_probes=1``: a table that gives up on a key after its first
   window), which breaks the guarantee that a build to the stated load
-  places every key and that every inserted key is found.  For lookup
-  cells only the lookup is bounded; the table is built soundly.
+  places every key and that every inserted key is found.  The cell's unit
+  file (``harness.make_unit``) says where it applies: for a lookup only
+  the lookup is bounded, and the table is built soundly.  A unit file
+  whose ``make`` takes no ``max_probes`` has no control.
 
 The benchmark's runs never run this.  It holds the chips in this process
 and, like ``run.py``, runs only on a TPU; the tests call ``readings`` at
@@ -33,21 +35,9 @@ CONTROL_PROBES = 1
 
 def readings(cell, seed: int, devices, max_probes=None) -> dict:
     """Every compared number of one pass over the cell's units."""
-    import jax
     import harness
-    import program
-    import traffic
 
-    sound = program.Program(cell.config, devices)
-    prog = program.Program(cell.config, devices, max_probes=max_probes)
-    pairs = jax.jit(traffic.pairs, static_argnums=0,
-                    out_shardings=prog.batch_sharding)
-    keys, values = pairs(prog.pairs, traffic.salt(seed))
-    if cell.traffic["op"] == "insert":
-        unit = harness.BuildUnit(prog, prog.build_fn(), keys, values)
-    else:
-        unit = harness.LookupUnit(prog, sound.build_fn(), keys, values,
-                                   cell.traffic, seed)
+    unit = harness.make_unit(cell, devices, seed, max_probes)
     w = harness.run_window(unit.call, unit.slots, 0.0, tally=unit.tally)
     checks, _ = unit.check(w, seed)
     return {name: c["value"] for name, c in checks.items()}

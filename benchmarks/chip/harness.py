@@ -2,23 +2,27 @@
 
 The cell (``BENCHMARK.json`` → ``workloads``) names a configuration and a
 traffic mix; ``configs/<config>.json`` holds the table's sizes and
-guarantees, ``traffic/<traffic>.json`` the mix, and ``metrics/<metric>.py``
-one reader per metric (or per quantity, ``reader_path``).  Adding a cell,
-a configuration, a mix or a metric adds files and entries; no code here
+guarantees, ``traffic/<traffic>.json`` the mix, ``units/<table>.<op>.py``
+the timed unit of the configuration's ``table`` kind under the mix's
+``op`` (``unit_path``), and ``metrics/<metric>.py`` one reader per metric
+(or per quantity, ``reader_path``).  Adding a cell, a configuration, a
+mix, a table kind, an op or a metric adds files and entries; no code here
 names one.
 
-A run: make the inputs on the device from the seed, compile and warm up
-the unit, time whole units back to back for ``seconds`` (the window ends
-with the first unit that finishes after it and completes a round over the
-mix's distinct query batches), then judge every retained answer against
-``reference`` (a build also through the library's read path, untimed) and
-read the cell's metrics.
+A run: the unit file's ``make`` makes the inputs on the device from the
+seed and compiles the unit (for a lookup, after building its table); one
+warm-up unit runs; then whole units are timed back to back for
+``seconds`` (the window ends with the first unit that finishes after it
+and completes a round over the unit's distinct inputs), every retained
+answer is judged by the unit's ``check`` against the plain reference, and
+the cell's metrics are read.
 """
 
 from __future__ import annotations
 
 import contextlib
 import importlib.util
+import inspect
 import json
 import shutil
 import sys
@@ -27,13 +31,8 @@ from dataclasses import dataclass, field
 from pathlib import Path
 
 import jax
-import numpy as np
 
-import bytes as floors
-import program
-import reference
 import trace_reduce
-import traffic
 
 HERE = Path(__file__).resolve().parent
 SPAN = "bench."
@@ -52,6 +51,7 @@ class Cell:
     end_to_end: list[str]
     per_layer: list[str]
     units: dict[str, str]
+    unit_file: Path
     bench_dir: Path = HERE
 
 
@@ -84,8 +84,48 @@ def load_cell(root: Path, name: str, bench_dir: Path = HERE) -> Cell:
              if mine(m) or (mine(m) is None and m["moves"] in e2e)]
     units = {m["name"]: m["unit"]
              for m in bench["end_to_end"] + bench["per_layer"]}
+    unit_file = unit_path(bench_dir, config["table"], mix["op"])
     return Cell(name, int(w["chips"]), config, mix, e2e, layer, units,
-                bench_dir)
+                unit_file, bench_dir)
+
+
+def unit_path(bench_dir: Path, table: str, op: str) -> Path:
+    """``units/<table>.<op>.py``: the timed unit of a configuration's
+    ``table`` kind under a mix's ``op``.  A kind or op with no file is an
+    error that names the file looked for."""
+    path = bench_dir / "units" / f"{table}.{op}.py"
+    if not path.is_file():
+        raise CellError(f"no unit for table {table!r} and op {op!r}: "
+                        f"{path} is missing")
+    return path
+
+
+def _load(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def make_unit(cell: Cell, devices, seed: int, max_probes: int | None = None):
+    """The cell's timed unit: ``make(cell, devices, seed)`` of its unit
+    file, which makes the inputs on the device and compiles (and, where
+    the unit needs one, builds its table).  The unit has ``slots`` and
+    ``per_call``, ``floor`` (the byte floor of one unit, ``bytes.py``),
+    ``footprints`` (compiled footprints), ``call(slot)``, ``tally`` (a
+    function of one call's output, or None), ``stats()`` (a dict of plain
+    numbers, or None) and ``check(window, seed) -> (checks, failed)``.
+
+    ``max_probes`` asks for the control (``control.py``); a unit file
+    whose ``make`` takes no ``max_probes`` has none."""
+    path = cell.unit_file
+    make = _load(path, f"unit_{path.stem}").make
+    if max_probes is None:
+        return make(cell, devices, seed)
+    if "max_probes" not in inspect.signature(make).parameters:
+        raise CellError(f"{path} has no control: its make takes no "
+                        "max_probes")
+    return make(cell, devices, seed, max_probes=max_probes)
 
 
 def load_peak(device_kind: str, bench_dir: Path = HERE) -> dict:
@@ -112,11 +152,7 @@ def reader_path(bench_dir: Path, name: str) -> Path:
 def read_metric(bench_dir: Path, name: str, run: dict):
     """``read(run)`` of the metric's reader (``reader_path``); None when it
     finds nothing to read in this run."""
-    path = reader_path(bench_dir, name)
-    spec = importlib.util.spec_from_file_location(f"metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(run)
+    return _load(reader_path(bench_dir, name), f"metric_{name}").read(run)
 
 
 # ---------------------------------------------------------------------------
@@ -172,11 +208,13 @@ def run_window(call, slots: int, seconds: float, tracing: bool = False,
 # one run
 # ---------------------------------------------------------------------------
 
-def _log(msg: str) -> None:
+def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
 
 
-def _compile(name: str, fn, *args):
+def compile_unit(name: str, fn, *args):
+    """(compiled ``fn`` for ``args``, its compiled footprint in bytes), with
+    the compile's seconds and memory logged."""
     t0 = time.perf_counter()
     compiled = fn.lower(*args).compile()
     secs = time.perf_counter() - t0
@@ -185,12 +223,12 @@ def _compile(name: str, fn, *args):
     if m is not None:
         foot = (m.temp_size_in_bytes + m.argument_size_in_bytes
                 + m.output_size_in_bytes - m.alias_size_in_bytes)
-        _log(f"compile {name}: {secs:.2f} s; temp_bytes="
-             f"{m.temp_size_in_bytes} argument_bytes="
-             f"{m.argument_size_in_bytes} output_bytes="
-             f"{m.output_size_in_bytes}")
+        log(f"compile {name}: {secs:.2f} s; temp_bytes="
+            f"{m.temp_size_in_bytes} argument_bytes="
+            f"{m.argument_size_in_bytes} output_bytes="
+            f"{m.output_size_in_bytes}")
     else:
-        _log(f"compile {name}: {secs:.2f} s; memory unreported")
+        log(f"compile {name}: {secs:.2f} s; memory unreported")
     return compiled, foot
 
 
@@ -207,29 +245,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
              peak: dict, t_start: float, trace_dir: Path | None = None
              ) -> dict:
     """Run ``cell`` once; return the contract line as a dict."""
-    cfg, mix = cell.config, cell.traffic
-    prog = program.Program(cfg, devices)
-    devices, n, op = prog.devices, prog.pairs, mix["op"]
-    _log(f"cell {cell.name}: {op} pairs={n} chips={prog.chips} "
-         f"slots_per_chip={prog.capacity_per_chip} seed={seed}")
-
-    pairs = jax.jit(traffic.pairs, static_argnums=0,
-                    out_shardings=prog.batch_sharding)
-    keys, values = pairs(n, traffic.salt(seed))
-    build, build_foot = _compile("build", prog.build_fn(), keys, values)
-    footprints = [build_foot]
-    if op == "insert":
-        unit = BuildUnit(prog, build, keys, values)
-    elif op == "retrieve":
-        unit = LookupUnit(prog, build, keys, values, mix, seed)
-        del keys, values
-        footprints.append(unit.footprint)
-    else:
-        raise CellError(f"unknown op {op!r}")
+    devices, op = list(devices)[:cell.chips], cell.traffic["op"]
+    log(f"cell {cell.name}: {cell.unit_file.name} chips={cell.chips} "
+        f"seed={seed}")
+    unit = make_unit(cell, devices, seed)
 
     jax.block_until_ready(unit.call(0))                  # warm-up unit
     setup_s = time.perf_counter() - t_start
-    _log(f"setup_s: {setup_s:.4f}")
+    log(f"setup_s: {setup_s:.4f}")
 
     if trace:
         shutil.rmtree(trace_dir, ignore_errors=True)
@@ -241,10 +264,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
         if trace:
             jax.profiler.stop_trace()
     slowest = sorted(range(w.calls), key=lambda i: -w.latencies_s[i])[:3]
-    _log(f"window: {w.calls} calls of {unit.per_call} keys in "
-         f"{w.elapsed_s:.4f} s; slowest calls (index, ms): "
-         f"{[(i, round(w.latencies_s[i] * 1e3, 3)) for i in slowest]}")
-    memory_peak = _memory_peak(devices, footprints)
+    log(f"window: {w.calls} calls of {unit.per_call} keys in "
+        f"{w.elapsed_s:.4f} s; slowest calls (index, ms): "
+        f"{[(i, round(w.latencies_s[i] * 1e3, 3)) for i in slowest]}")
+    memory_peak = _memory_peak(devices, unit.footprints)
 
     reduced = stats = None
     if trace:
@@ -255,7 +278,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
         stats = unit.stats()
     checks, failed = unit.check(w, seed)
 
-    run = {"cell": cell.name, "op": op, "chips": prog.chips,
+    run = {"cell": cell.name, "op": op, "chips": cell.chips,
            "setup_s": setup_s, "peak": peak, "trace": reduced,
            "stats": stats, "floor_bytes": unit.floor * w.calls,
            "window": {"calls": w.calls, "keys": unit.per_call * w.calls,
@@ -270,9 +293,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
             metrics[name] = {"value": float(value), "unit": cell.units[name]}
 
     for name, c in checks.items():
-        _log(f"check {name}: {c['value']} limit {c['limit']}")
+        log(f"check {name}: {c['value']} limit {c['limit']}")
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
-              "count": prog.chips, "memory_peak_bytes": memory_peak}
+              "count": cell.chips, "memory_peak_bytes": memory_peak}
     line = {"correct": all(c["value"] <= c["limit"] for c in checks.values()),
             "attempted": unit.per_call * w.calls, "failed": failed,
             "metrics": metrics, "device": device}
@@ -285,115 +308,6 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     return line
 
 
-def _limit0(values: dict) -> dict:
+def limit0(values: dict) -> dict:
     """Exact comparisons: every count of disagreements has the limit 0."""
     return {k: {"value": int(v), "limit": 0} for k, v in values.items()}
-
-
-class BuildUnit:
-    """A unit is a whole build: create a fresh table, insert every pair."""
-
-    slots = 1
-
-    def __init__(self, prog, build, keys, values):
-        self.prog, self.build = prog, build
-        self.keys, self.values = keys, values
-        self.per_call = prog.pairs
-        cfg = prog.cfg
-        self.floor = floors.insert_floor(
-            prog.pairs, prog.capacity_per_chip * prog.chips,
-            cfg["key_words"], cfg["value_words"])
-
-    def call(self, _slot):
-        return self.build(self.keys, self.values)
-
-    @staticmethod
-    def tally(out):
-        return out[1]              # per shard: [inserted, full, overflow]
-
-    def stats(self):
-        fn = self.prog.build_stats_fn()
-        return None if fn is None else _stats_dict(fn(self.keys, self.values))
-
-    def check(self, w: Window, seed: int):
-        tallies = np.asarray(jax.device_get(w.tallies)).reshape(-1, 3)
-        refused = int(len(w.tallies) * self.per_call - tallies[:, 0].sum())
-        table = w.kept[0][0]
-        slot_keys, slot_values, count = program.Program.slots(table)
-        home = self.prog.devices[0]
-        got = reference.table_disagreements(
-            jax.device_put(slot_keys, home), jax.device_put(slot_values, home),
-            jax.device_put(count, home), traffic.salt(seed),
-            n=self.prog.pairs)
-        got = dict(jax.device_get(got))
-        got.update(self._read_back(table, seed))
-        got["refused"] = refused
-        if self.prog.chips > 1:
-            got["overflow"] = int(tallies[:, 2].sum())
-            got["misplaced"] = int(self.prog.misplaced_fn()(table))
-        return _limit0(got), refused
-
-
-    def _read_back(self, table, seed: int) -> dict:
-        """Every inserted key and ``n // 64`` absent ones, read from the
-        window's last table through the library's own read path (after the
-        window, untimed), against the truth: a key stored outside the slots
-        its probe sequence reaches reads as missing."""
-        prog = self.prog
-        idx = traffic.readback_indices(prog.pairs)
-        keys = jax.jit(traffic.keys_of,
-                       out_shardings=prog.batch_sharding)(idx)
-        t0 = time.perf_counter()
-        vals, found = jax.block_until_ready(prog.readback_fn()(table, keys))
-        _log(f"read-back: {idx.shape[0]} keys in "
-             f"{time.perf_counter() - t0:.2f} s (compile included)")
-        home = prog.devices[0]
-        got = reference.answer_disagreements(
-            jax.device_put(idx, home), jax.device_put(vals, home),
-            jax.device_put(found, home), traffic.salt(seed), n=prog.pairs)
-        return {"read_" + k: int(v) for k, v in jax.device_get(got).items()}
-
-
-class LookupUnit:
-    """The table is built in set-up; a unit is one lookup call."""
-
-    def __init__(self, prog, build, keys, values, mix, seed):
-        self.prog = prog
-        self.table, _ = jax.block_until_ready(build(keys, values))
-        n = prog.pairs
-        self.idx = traffic.query_indices(mix, n, seed)
-        self.slots, self.per_call = traffic.batch_shape(mix, n)
-        self.batches = [traffic.keys_of(self.idx[i])
-                        for i in range(self.slots)]
-        self.lookup, self.footprint = _compile(
-            "lookup", prog.lookup_fn(), self.table, self.batches[0])
-        n_present = round(self.per_call * float(mix["present_share"]))
-        cfg = prog.cfg
-        self.floor = floors.lookup_floor(
-            n_present, self.per_call - n_present, cfg["key_words"],
-            cfg["value_words"])
-
-    tally = None
-
-    def call(self, slot):
-        return self.lookup(self.table, self.batches[slot])
-
-    def stats(self):
-        fn = self.prog.lookup_stats_fn()
-        return _stats_dict(fn(self.table, self.batches[0]))
-
-    def check(self, w: Window, seed: int):
-        total = {"found_wrong": 0, "values_wrong": 0}
-        for slot, (vals, found) in w.kept.items():
-            got = reference.answer_disagreements(
-                self.idx[slot], vals, found, traffic.salt(seed),
-                n=self.prog.pairs)
-            for k, v in jax.device_get(got).items():
-                total[k] += int(v)
-        return _limit0(total), 0
-
-
-def _stats_dict(s) -> dict:
-    s = jax.device_get(s)
-    return {"fixpoint_iters": int(s.fixpoint_iters),
-            "probe_sum": int(s.probe_sum), "probe_n": int(s.probe_n)}

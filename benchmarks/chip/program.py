@@ -1,11 +1,13 @@
-"""The system under test, as the benchmark drives it.
+"""The single-value table under test, as the benchmark drives it.
 
-Everything that calls the library is here: the table a configuration
-names, the timed units (a whole build of a fresh table; one lookup call),
-the read path that reads a built table back for the check,
-the ``stats=True`` twins that the traced run reads counters from, and the
-program's own ownership invariant for sharded tables.  The harness times
-these; ``reference`` judges what they return.
+Every call of the library that the single-value units
+(``units/single_value.insert.py``, ``units/single_value.retrieve.py``)
+make is here: the table a configuration names, the timed programs (a
+whole build of a fresh table; one lookup call, which also reads a built
+table back for a build's check), the ``stats=True`` twins that the traced
+run reads counters from, and the program's own ownership invariant for
+sharded tables.  The harness times the units; ``reference`` judges what
+they return.
 """
 
 from __future__ import annotations
@@ -15,6 +17,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import distributed as dist
@@ -34,8 +37,6 @@ class Program:
     """
 
     def __init__(self, cfg: dict, devices, max_probes: int | None = None):
-        if cfg["table"] != "single_value":
-            raise ValueError(f"table kind {cfg['table']!r} is not driven yet")
         self.cfg = cfg
         self.chips = int(cfg["chips"])
         self.devices = list(devices)[:self.chips]
@@ -93,27 +94,21 @@ class Program:
         return jax.jit(unit)
 
     def lookup_fn(self):
-        """jit: (table, keys) -> (values, found)."""
-        if self.mesh is not None:
-            raise ValueError("sharded lookups are not driven yet")
-        max_probes = self.max_probes
+        """jit: (table, keys) -> (values, found) through the library's read
+        path: ``retrieve`` on one chip, ``shard_retrieve`` (the ownership
+        exchange, then the local walk) across chips, with the keys placed
+        by ``batch_sharding``."""
+        max_probes, mesh = self.max_probes, self.mesh
+        slack = self.cfg["exchange_slack"]
 
         def unit(table, keys):
             if max_probes is not None:
                 table = dataclasses.replace(table, max_probes=max_probes)
-            return sv.retrieve(table, keys)
+            if mesh is None:
+                return sv.retrieve(table, keys)
+            return dist.shard_retrieve(mesh, AXIS, table, keys,
+                                       slack=slack)[:2]
         return jax.jit(unit)
-
-    def readback_fn(self):
-        """jit: (table, keys) -> (values, found) through the library's read
-        path: ``retrieve`` on one chip, ``shard_retrieve`` (the ownership
-        exchange, then the local walk) across chips.  Reads a built table
-        for the check, never timed."""
-        if self.mesh is None:
-            return self.lookup_fn()
-        mesh, slack = self.mesh, self.cfg["exchange_slack"]
-        return jax.jit(lambda t, k: dist.shard_retrieve(
-            mesh, AXIS, t, k, slack=slack)[:2])
 
     # -- counters for the traced run (never timed) ------------------------------
     def build_stats_fn(self):
@@ -124,6 +119,10 @@ class Program:
         return jax.jit(lambda k, v: self._insert(k, v, stats=True)[3][0])
 
     def lookup_stats_fn(self):
+        """jit: (table, keys) -> TableStats of one lookup, or None when the
+        sharded path carries none."""
+        if self.mesh is not None:
+            return None
         return jax.jit(lambda t, k: sv.retrieve(t, k, stats=True)[2])
 
     # -- reading a built table ----------------------------------------------
@@ -149,3 +148,10 @@ class Program:
             return jnp.sum(live & (owner != shard), dtype=_I)
         return jax.jit(misplaced)
 
+
+def stats_dict(stats) -> dict:
+    """Every ``TableStats`` field as plain numbers (a histogram as a list),
+    for the metric readers."""
+    stats = jax.device_get(stats)
+    return {f.name: np.asarray(getattr(stats, f.name)).tolist()
+            for f in dataclasses.fields(stats)}

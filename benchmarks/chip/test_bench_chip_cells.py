@@ -1,8 +1,9 @@
 """CPU rehearsal of the chip benchmark at tiny sizes.
 
 Each cell's unit runs under ``JAX_PLATFORMS=cpu`` with its configuration
-cut to a few hundred pairs (``dist4_build`` on four virtual devices, in a
-child process), and the result line is checked for form.  The timed path is
+cut to a few hundred pairs (``dist4_build``, and a sharded lookup, on four
+virtual devices, in a child process), and the result line is checked for
+form.  The timed path is
 broken underneath in each way the cell can be, and ``correct`` has to come
 out false; so does the control.  These call ``harness.run_cell`` directly,
 past ``run.py``'s look for a TPU, which has tests of its own here.
@@ -26,8 +27,10 @@ import pytest
 import bytes as floors
 import control
 import harness
+import program
 import traffic
-from repro.core import distributed, single_value
+from repro.core import counting, distributed, single_value
+from repro.obs import metrics
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
@@ -159,6 +162,130 @@ def test_a_split_metric_is_read_by_its_quantity(root):
         harness.reader_path(bench_dir, "no_such.metric")
 
 
+# A table kind that no file of the benchmark names: a counting table built
+# from every key repeated ``multiplicity`` times, judged by each key's count.
+COUNT_UNIT = '''"""A whole build of a counting table from repeated keys."""
+
+import jax
+import jax.numpy as jnp
+
+import harness
+import traffic
+from repro.core import counting
+
+
+def make(cell, devices, seed):
+    cfg = cell.config
+    n, r = cfg["keys"], cfg["multiplicity"]
+    distinct = traffic.keys_of(jnp.arange(n, dtype=jnp.uint32))
+    keys = jax.random.permutation(traffic.rng(seed), jnp.tile(distinct, r))
+
+    def build(keys):
+        table = counting.create(round(n / cfg["load"]), window=cfg["window"])
+        return counting.insert(table, keys)[0]
+
+    build, foot = harness.compile_unit("build", jax.jit(build), keys)
+    return Unit(build, foot, keys, distinct, r)
+
+
+class Unit:
+    slots, floor, tally = 1, 0, None
+
+    def __init__(self, build, foot, keys, distinct, r):
+        self.build, self.keys = build, keys
+        self.distinct, self.r = distinct, r
+        self.footprints = [foot]
+        self.per_call = keys.shape[0]
+
+    def call(self, _slot):
+        return self.build(self.keys)
+
+    def stats(self):
+        return None
+
+    def check(self, w, seed):
+        got = counting.counts(w.kept[0], self.distinct)
+        wrong = int(jnp.sum(got != self.r))
+        return harness.limit0({"counts_wrong": wrong}), 0
+'''
+
+
+def _tree(root: Path) -> dict:
+    return {f: f.read_bytes() for f in root.rglob("*") if f.is_file()}
+
+
+def test_added_table_kind_is_files_only(root, monkeypatch):
+    """A later PR adds a table kind and an op as one unit file, a
+    configuration, a mix, a reader and entries; no existing file but
+    ``BENCHMARK.json`` changes, and the kind runs, is judged, and has no
+    control."""
+    bench_dir = root / "benchmarks" / "chip"
+    before = _tree(root)
+    (bench_dir / "units" / "occurrence_counter.count_all.py").write_text(
+        COUNT_UNIT)
+    (bench_dir / "configs" / "occ_r3.json").write_text(json.dumps(
+        {"name": "occ_r3", "table": "occurrence_counter", "chips": 1,
+         "keys": 128, "multiplicity": 3, "load": 0.5, "window": 32}))
+    (bench_dir / "traffic" / "repeated.json").write_text(json.dumps(
+        {"op": "count_all"}))
+    (bench_dir / "metrics" / "count_rate.py").write_text(
+        "def read(run):\n    w = run['window']\n"
+        "    return w['keys'] / w['elapsed_s']\n")
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({
+        "name": "occ_r3", "source": "test", "reduced": [], "why": "test",
+        "file": "benchmarks/chip/configs/occ_r3.json"})
+    bench["workloads"].append({"name": "occ_build", "config": "occ_r3",
+                               "traffic": "repeated", "chips": 1,
+                               "why": "test"})
+    bench["end_to_end"].append({
+        "name": "count_rate", "unit": "keys/s", "better": "higher",
+        "bound": 0.01, "source": "host_clock", "workloads": ["occ_build"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    after = _tree(root)
+    assert {f for f in before if before[f] != after[f]} == {
+        root / "BENCHMARK.json"}
+
+    line = run(root, "occ_build")
+    assert line["correct"] is True
+    assert line["attempted"] == 3 * 128
+    assert set(line["metrics"]) == {"count_rate", "setup_s"}
+    assert line["checks"] == {"counts_wrong": {"value": 0, "limit": 0}}
+
+    real = counting.insert
+    monkeypatch.setattr(counting, "insert", lambda table, keys, mask=None,
+                        stats=False: real(table, keys[:keys.shape[0] // 2]))
+    line = run(root, "occ_build")
+    assert line["correct"] is False
+    assert line["checks"]["counts_wrong"]["value"] > 0
+
+    cell = harness.load_cell(root, "occ_build", bench_dir)
+    with pytest.raises(harness.CellError, match="no control"):
+        control.readings(cell, SEED, jax.devices(), control.CONTROL_PROBES)
+
+
+@pytest.mark.parametrize("field,value,unit", [
+    ("table", "no_such_kind", "units/no_such_kind.insert.py"),
+    ("op", "no_such_op", "units/single_value.no_such_op.py")])
+def test_unknown_kind_or_op_names_its_unit_file(root, field, value, unit):
+    bench_dir = root / "benchmarks" / "chip"
+    path = bench_dir / ("configs/sv_u32_w32.json" if field == "table"
+                        else "traffic/bulk_build.json")
+    data = json.loads(path.read_text())
+    data[field] = value
+    path.write_text(json.dumps(data))
+    with pytest.raises(harness.CellError, match=unit):
+        harness.load_cell(root, "sv_build", bench_dir)
+
+
+def test_stats_carry_every_table_stats_field():
+    got = program.stats_dict(metrics.empty())
+    assert set(got) == {f.name for f in dataclasses.fields(metrics.TableStats)}
+    json.dumps(got)
+    assert got["tail_rounds"] == 0 and got["load_factor"] == 0.0
+    assert isinstance(got["probe_hist"], list)
+
+
 # -- faults planted in the timed path ------------------------------------------
 
 _insert, _retrieve = single_value.insert, single_value.retrieve
@@ -272,6 +399,38 @@ def _dist_child(dest: str) -> None:
     cell = harness.load_cell(root, "dist4_build", root / "benchmarks" / "chip")
     out["control"] = control.readings(cell, SEED, jax.devices(),
                                       control.CONTROL_PROBES)
+
+    # the retrieve leg: the sharded table looked up through shard_retrieve
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["workloads"].append({"name": "dist4_lookup",
+                               "config": "dist4_sv_u32_w32",
+                               "traffic": "bulk_lookup", "chips": 4,
+                               "why": "test"})
+    for m in bench["end_to_end"]:
+        if m["name"] == "lookup_rate":
+            m["workloads"].append("dist4_lookup")
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    out["lookup_sound"] = run(root, "dist4_lookup")
+
+    def local_retrieve(table, keys, axis, slack=2.0):
+        return (*single_value.retrieve(table, keys), jnp.zeros((), jnp.int32))
+
+    for fault, (mod, attr, fake) in {
+            "lookup_half_batch": (single_value, "retrieve", _retrieve_half),
+            "lookup_answer_altered": (single_value, "retrieve",
+                                      _retrieve_altered),
+            "lookup_exchange_left_out": (distributed, "retrieve_distributed",
+                                         local_retrieve)}.items():
+        real = getattr(mod, attr)
+        setattr(mod, attr, fake)
+        try:
+            out[fault] = run(root, "dist4_lookup")
+        finally:
+            setattr(mod, attr, real)
+    cell = harness.load_cell(root, "dist4_lookup",
+                             root / "benchmarks" / "chip")
+    out["lookup_control"] = control.readings(cell, SEED, jax.devices(),
+                                             control.CONTROL_PROBES)
     print(json.dumps(out))
 
 
@@ -296,6 +455,16 @@ def test_dist4_build_on_four_virtual_devices(tmp_path):
     assert out["exchange_left_out"]["checks"]["misplaced"]["value"] > 0
     assert out["scattered"]["checks"]["read_found_wrong"]["value"] > 0
     assert max(out["control"].values()) > 0
+    sound = out["lookup_sound"]
+    assert sound["correct"] is True and sound["device"]["count"] == 4
+    assert set(sound["metrics"]) == {"lookup_rate", "setup_s"}
+    assert sound["attempted"] == 4 * PAIRS
+    for fault in ("lookup_half_batch", "lookup_answer_altered",
+                  "lookup_exchange_left_out"):
+        assert out[fault]["correct"] is False, fault
+    assert out["lookup_exchange_left_out"]["checks"]["found_wrong"][
+        "value"] > 0
+    assert max(out["lookup_control"].values()) > 0
 
 
 def test_seed_draws_order_and_values_not_the_work():

@@ -98,6 +98,13 @@ def pairs(n: int, salt):
     return keys, values_of(keys, salt)
 
 
+def device_pairs(n: int, seed: int, sharding):
+    """``pairs(n, salt(seed))`` made on the device in one jitted call and
+    placed by ``sharding``."""
+    return jax.jit(pairs, static_argnums=0, out_shardings=sharding)(
+        n, salt(seed))
+
+
 def readback_indices(n: int):
     """The indices a build's check reads back: every present one
     (``0..n-1``) and ``n // 64`` absent ones after them."""
